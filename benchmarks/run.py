@@ -23,6 +23,7 @@ from pathlib import Path
 from benchmarks import (bench_destinations, bench_ga, bench_kernels,
                         bench_mriq, bench_narrowing, bench_power,
                         bench_roofline, bench_transfer)
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "mriq": bench_mriq,
@@ -56,6 +57,7 @@ def _export_fleet_baseline() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated suite names")

@@ -7,8 +7,6 @@ and recording HLO census + analytic roofline terms before/after.
     PYTHONPATH=src python scripts/hillclimb.py
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import json
 import sys
 from pathlib import Path
@@ -18,7 +16,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 from repro.configs import SHAPES, get_config                  # noqa: E402
 from repro.core.intensity import estimate_program             # noqa: E402
 from repro.core.power import PowerModel, V5E                  # noqa: E402
-from repro.launch.dryrun import run_cell                      # noqa: E402
+from repro.launch.dryrun import run_cell, setup_host_devices  # noqa: E402
 
 OUT = Path(__file__).resolve().parents[1] / "artifacts" / "hillclimb"
 POWER = PowerModel(V5E)
@@ -82,6 +80,7 @@ def log_iter(cell, name, hypothesis, m_before, m_after, notes=""):
 
 
 def main():
+    setup_host_devices()
     OUT.mkdir(parents=True, exist_ok=True)
     log = []
 

@@ -3,9 +3,6 @@ plan and compare the roofline terms against the baseline artifacts.
 
     PYTHONPATH=src python scripts/optimize_all.py
 """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import json
 import sys
 from pathlib import Path
@@ -16,7 +13,7 @@ from repro.configs import SHAPES, get_config, list_archs   # noqa: E402
 from repro.configs.optimized import optimized_plan          # noqa: E402
 from repro.core.intensity import estimate_program           # noqa: E402
 from repro.core.power import PowerModel, V5E                # noqa: E402
-from repro.launch.dryrun import run_cell                    # noqa: E402
+from repro.launch.dryrun import run_cell, setup_host_devices  # noqa: E402
 
 POWER = PowerModel(V5E)
 CHIPS = 256
@@ -39,6 +36,7 @@ def terms(rec, cfg, shape, plan):
 
 
 def main():
+    setup_host_devices()
     rows = []
     print(f"{'cell':44s} {'base_t':>9s} {'opt_t':>9s} {'speedup':>8s} "
           f"{'roofl':>13s} {'status'}")

@@ -333,10 +333,12 @@ class CompiledBackend:
                "--plan-json", plan_json, "--tag", tag]
         if self.multi_pod:
             cmd.append("--multi-pod")
-        # inherit the parent environment (JAX_PLATFORMS & friends must
-        # survive), pin only the import path; the child pins its own
-        # XLA_FLAGS via setup_host_devices()
-        env = dict(os.environ, PYTHONPATH=str(REPO_ROOT / "src"))
+        # inherit the parent environment, pin the import path and the CPU
+        # platform: the child compiles for 512 placeholder host devices
+        # (its own XLA_FLAGS, via setup_host_devices) and must never
+        # reach for an accelerator the parent may be holding
+        env = dict(os.environ, PYTHONPATH=str(REPO_ROOT / "src"),
+                   JAX_PLATFORMS="cpu")
         run = self.runner or subprocess.run
         try:
             run(cmd, timeout=ctx.timeout_s, capture_output=True,

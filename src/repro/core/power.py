@@ -42,6 +42,29 @@ V5E = HardwareSpec(
     p_static=65.0,
 )
 
+#: modeled spec per ``jax.Device.device_kind`` (a v5e reports "TPU v5 lite")
+SPECS_BY_KIND = {"TPU v5 lite": V5E}
+
+
+def modeled_spec(device=None) -> HardwareSpec:
+    """The modeled hardware spec of ``device`` (default: jax's first).
+
+    A TPU whose kind is not in ``SPECS_BY_KIND`` raises rather than
+    borrowing another chip's watts.  Any other platform (the CPU that
+    runs the tests) models the v5e this repository targets: its watts
+    are *modeled* v5e watts, not a reading of the host."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return V5E
+    try:
+        return SPECS_BY_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(f"no modeled hardware spec for TPU kind "
+                         f"{device.device_kind!r}: add it to "
+                         f"repro.core.power.SPECS_BY_KIND") from None
+
 # The paper's evaluated node (Dell R740 + Arria10 FPGA): used by the MRI-Q
 # reproduction to cross-check the *measured* numbers of Fig. 5.
 @dataclass(frozen=True)

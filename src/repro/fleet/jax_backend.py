@@ -17,9 +17,11 @@ This module implements the booking plane as a jit-compiled
 dense (one ``[n]``/``[n, t]`` row set per live step or quiet stretch),
 padded with no-op zero records to the chunk size so one compilation
 serves the whole run, and folded into float64 carry tensors under
-``jax.experimental.enable_x64`` — scoped, never the global flag, so
-co-resident jax code keeps its default precision.  The carries are
-added into the fleet's numpy cell tensors at ``finalize``.
+``jax.enable_x64`` — scoped, never the global flag, so co-resident jax
+code keeps its default precision.  The carries are added into the
+fleet's numpy cell tensors at ``finalize``.  Every kernel here runs on
+the host's CPU device, named explicitly (``_on_host``): this is float64
+bookkeeping, and it stays off the accelerator that serves the model.
 
 Float contract: every scan operation is an elementwise add or
 max-compare mirroring the numpy accumulator, so the jax path lands
@@ -29,18 +31,26 @@ counts and placement events stay exact.
 """
 from __future__ import annotations
 
+from contextlib import contextmanager
+
 import numpy as np
 
 try:                                    # pragma: no cover - import gate
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     HAVE_JAX = True
-except Exception:                       # pragma: no cover
+except ImportError:                     # pragma: no cover
     jax = None
     jnp = None
-    enable_x64 = None
     HAVE_JAX = False
+
+
+@contextmanager
+def _on_host():
+    """Run on the CPU device in float64 (scoped, not the global flag)."""
+    with jax.default_device(jax.devices("cpu")[0]), jax.enable_x64(True):
+        yield
+
 
 #: records folded per compiled scan call (padded to this length)
 CHUNK = 64
@@ -106,7 +116,7 @@ def route_argmin_jax(marg, load, rank, active):
     global _route_kernel
     if not HAVE_JAX:
         raise RuntimeError("route_argmin_jax needs jax installed")
-    with enable_x64():
+    with _on_host():
         if _route_kernel is None:
             _route_kernel = _build_route_kernel()
         return int(_route_kernel(jnp.asarray(marg, jnp.float64),
@@ -173,7 +183,7 @@ def expected_queue_depth_many_jax(servers, service_time, lam,
         return np.zeros(0)
     service_time = max(float(service_time), _MIN_GAP_J)
     c_max = int(servers.max())
-    with enable_x64():
+    with _on_host():
         key = (c_max, servers.size)
         kern = _lq_kernels.get(key)
         if kern is None:
@@ -251,7 +261,7 @@ class JaxAccumulator:
         self.n, self.t = n, t
         self._dec_recs: list = []
         self._idle_recs: list = []
-        with enable_x64():
+        with _on_host():
             z_nt = jnp.zeros((n, t), jnp.float64)
             z_nti = jnp.zeros((n, t), jnp.int64)
             z_n = jnp.zeros(n, jnp.float64)
@@ -326,7 +336,7 @@ class JaxAccumulator:
             return
         recs = self._pad_stack(self._dec_recs, CHUNK)
         self._dec_recs = []
-        with enable_x64():
+        with _on_host():
             jrecs = tuple(jnp.asarray(a) for a in recs)
             self._dec_carry = self._dec_fold(self._dec_carry, jrecs)
 
@@ -335,7 +345,7 @@ class JaxAccumulator:
             return
         recs = self._pad_stack(self._idle_recs, CHUNK)
         self._idle_recs = []
-        with enable_x64():
+        with _on_host():
             jrecs = tuple(jnp.asarray(a) for a in recs)
             self._idle_carry = self._idle_fold(self._idle_carry, jrecs)
 

@@ -49,11 +49,13 @@ class Node:
               clock: Callable[[], float] = time.perf_counter,
               nominal_step_s: float = 2e-3) -> "Node":
         """Wire a full serving node — the bundle ``launch.serve`` used to
-        assemble by hand for its single loop."""
+        assemble by hand for its single loop.  Without an ``envelope``
+        the meter models the device jax serves on
+        (``repro.core.power.modeled_spec``)."""
         if envelope is None:
-            from repro.core.power import V5E
+            from repro.core.power import modeled_spec
             from repro.telemetry.dvfs import envelope_for
-            envelope = envelope_for(V5E)
+            envelope = envelope_for(modeled_spec())
         meter = DecodeEnergyMeter(envelope=envelope, chips=chips,
                                   source=source, node=name)
         loop = ServeLoop(model, params, batch_slots=slots, max_seq=max_seq,

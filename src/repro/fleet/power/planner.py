@@ -253,17 +253,12 @@ class FleetPowerPlanner:
     def _lq_sweep(self, slots_c, service: float, step: int,
                   horizon: float):
         """Expected queue depth for every candidate slot count — the
-        jit kernel when ``backend="jax"``, the numpy sweep otherwise
-        (and as the fallback if the jit path raises)."""
+        jit kernel when ``backend="jax"``, the numpy sweep otherwise."""
         if self.backend == "jax":
             from repro.fleet.jax_backend import \
                 expected_queue_depth_many_jax
-            try:
-                return expected_queue_depth_many_jax(
-                    slots_c, service,
-                    self.forecaster.rate(now=step), horizon)
-            except Exception:           # pragma: no cover - jit trouble
-                pass
+            return expected_queue_depth_many_jax(
+                slots_c, service, self.forecaster.rate(now=step), horizon)
         return self.forecaster.expected_queue_depth_many(
             slots_c, service, now=step, horizon=horizon)
 

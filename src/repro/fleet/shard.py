@@ -206,7 +206,11 @@ class ShardAccumulator:
             _init_parts(parts)
             self._parts.append(parts)
         if self.mode == "process":
-            ctx = get_context("fork")
+            # workers come from a fork server that imported this module
+            # and nothing else: never a fork of a parent whose jax may
+            # hold an accelerator and its threads.  Workers touch no jax.
+            ctx = get_context("forkserver")
+            ctx.set_forkserver_preload([__name__])
             for s in range(shards):
                 parent, child = ctx.Pipe()
                 p = ctx.Process(

@@ -1,3 +1,16 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the compute sites the offload search can move off XLA.
+
+Every kernel takes ``interpret=None``, which means: compile for the chip
+when the default backend is a TPU, run the Pallas interpreter otherwise.
+The choice is made when the call is traced, never at import time.
+"""
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` -> interpret unless the default backend is a TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

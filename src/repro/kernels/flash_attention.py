@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -70,8 +72,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q (B,S,Hq,D); k,v (B,T,Hkv,D) -> (B,S,Hq,D)."""
+    interpret = resolve_interpret(interpret)
     b, s, hq, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
@@ -106,8 +109,8 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                         pltpu.VMEM((block_q,), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(mosaic=dict(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))) if not interpret else None,
+                                 "arbitrary")),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -1,10 +1,15 @@
 """jit'd public wrappers for the Pallas kernels (the 'pallas' destination).
 
 These are what the model layers call when the offload plan selects the
-Pallas rung.  Each wrapper normalizes layouts, picks hardware-aligned block
-shapes and falls back to the pure-jnp oracle when the shape cannot be tiled
-(odd sizes below one block).  ``interpret=True`` everywhere in this
-container (CPU validation of TPU-targeted kernels).
+Pallas rung.  Each wrapper normalizes layouts, picks block shapes and
+falls back to the pure-jnp oracle when the shape cannot be tiled.  Whether
+a kernel is compiled for the chip or run in the Pallas interpreter is
+decided from the platform when the call is traced
+(``repro.kernels.resolve_interpret``).  A compiled kernel gets only
+tiling-aligned blocks: a multiple of the 8-row sublane or 128-wide lane
+tile, as the dimension requires, or the whole dimension.  Each fallback
+to the oracle counts on ``repro.obs`` as ``kernel_fallback_<kernel>``
+(once per trace, not per call).
 
 Every op carries a ``jax.custom_vjp``: the forward runs the Pallas kernel,
 the backward differentiates the pure-jnp oracle (rematerialized) — so the
@@ -18,33 +23,81 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ref as _ref
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.mriq import mriq_pallas as _mriq
 from repro.kernels.rglru import rglru_pallas as _rglru
 from repro.kernels.ssd import ssd_pallas as _ssd
 from repro.kernels.swiglu import swiglu_pallas as _swiglu
 
-INTERPRET = True    # CPU container: Pallas kernels validated in interpret mode
+SUBLANE, LANE = 8, 128      # the TPU tile of a block's last two dimensions
 
 
-def _blk(n: int, target: int) -> int:
-    """Largest divisor of n that is <= target (hardware-aligned when possible)."""
-    b = min(target, n)
-    while n % b:
-        b -= 1
+def _blk(n: int, target: int, align: int = 1) -> int:
+    """The whole dimension when it is at most ``target``, else the largest
+    divisor of n that is <= target and a multiple of ``align``; 0 when
+    there is none."""
+    if n <= target:
+        return n
+    b = target - target % align
+    while b > 0 and n % b:
+        b -= align
     return b
+
+
+def _compiled() -> bool:
+    return not resolve_interpret(None)
+
+
+def _align(tile: int, compiled: bool) -> int:
+    return tile if compiled else 1
+
+
+def _fallback(kernel: str) -> None:
+    obs.METRICS.counter(
+        f"kernel_fallback_{kernel}",
+        "traces that ran the jnp oracle: no block tiles the shape").inc()
+
+
+def flash_blocks(s: int, t: int, compiled: bool) -> tuple[int, int]:
+    """(block_q, block_k) for S queries over T keys."""
+    a = _align(SUBLANE, compiled)
+    return _blk(s, 128, a), _blk(t, 128, a)
+
+
+def mriq_blocks(n: int, m: int, compiled: bool) -> tuple[int, int]:
+    """(block_n, block_m) for N voxels (lanes) and M k-samples (rows)."""
+    return (_blk(n, 512, _align(LANE, compiled)),
+            _blk(m, 512, _align(SUBLANE, compiled)))
+
+
+def rglru_blocks(s: int, w: int, compiled: bool) -> tuple[int, int]:
+    """(block_t, block_w) for S steps over width W."""
+    return (_blk(s, 128, _align(SUBLANE, compiled)),
+            _blk(w, 512, _align(LANE, compiled)))
+
+
+def ssd_chunk(s: int, chunk: int, compiled: bool) -> int:
+    """Chunk length for S steps (a lane row of the decay in the kernel)."""
+    return _blk(s, chunk, _align(LANE, compiled))
+
+
+def swiglu_blocks(t: int, f: int, compiled: bool) -> tuple[int, int]:
+    """(block_t, block_f) for T tokens through a d_ff of F."""
+    return (_blk(t, 256, _align(SUBLANE, compiled)),
+            _blk(f, 256, _align(LANE, compiled)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_op(q, k, v, causal, window):
-    s, t = q.shape[1], k.shape[1]
-    bq = _blk(s, 128)
-    bk = _blk(t, 128)
+    bq, bk = flash_blocks(q.shape[1], k.shape[1], _compiled())
     if bq < 8 or bk < 8:
+        _fallback("flash_attention")
         return _ref.flash_attention_ref(q, k, v, causal, window)
     return _flash(q, k, v, causal=causal, window=window,
-                  block_q=bq, block_k=bk, interpret=INTERPRET)
+                  block_q=bq, block_k=bk)
 
 
 def _flash_fwd(q, k, v, causal, window):
@@ -66,23 +119,23 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0):
     return _flash_op(q, k, v, causal, window)
 
 
-def mriq(kx, ky, kz, phi_mag, x, y, z, block_n: int = 512,
-         block_m: int = 512):
-    bn = _blk(x.shape[0], block_n)
-    bm = _blk(kx.shape[0], block_m)
-    return _mriq(kx, ky, kz, phi_mag, x, y, z, block_n=bn, block_m=bm,
-                 interpret=INTERPRET)
+def mriq(kx, ky, kz, phi_mag, x, y, z):
+    bn, bm = mriq_blocks(x.shape[0], kx.shape[0], _compiled())
+    if not (bn and bm):
+        _fallback("mriq")
+        return _ref.mriq_ref(kx, ky, kz, phi_mag, x, y, z)
+    return _mriq(kx, ky, kz, phi_mag, x, y, z, block_n=bn, block_m=bm)
 
 
 @jax.custom_vjp
 def rglru(log_a, b):
-    bsz, s, w = log_a.shape
-    bw = _blk(w, 512)
-    bt = _blk(s, 128)
+    _, s, w = log_a.shape
+    bt, bw = rglru_blocks(s, w, _compiled())
     if bw < 8 or bt < 8:
+        _fallback("rglru")
         return _ref.rglru_ref(log_a, b)
     return _rglru(log_a.astype(jnp.float32), b.astype(jnp.float32),
-                  block_w=bw, block_t=bt, interpret=INTERPRET)
+                  block_w=bw, block_t=bt)
 
 
 def _rglru_fwd(log_a, b):
@@ -100,11 +153,11 @@ rglru.defvjp(_rglru_fwd, _rglru_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _ssd_op(x, dt, A, Bm, Cm, chunk):
-    s = x.shape[1]
-    q = _blk(s, chunk)
+    q = ssd_chunk(x.shape[1], chunk, _compiled())
     if q < 8:
+        _fallback("ssd")
         return _ref.ssd_ref(x, dt, A, Bm, Cm, max(q, 1))
-    return _ssd(x, dt, A, Bm, Cm, chunk=q, interpret=INTERPRET)
+    return _ssd(x, dt, A, Bm, Cm, chunk=q)
 
 
 def _ssd_fwd(x, dt, A, Bm, Cm, chunk):
@@ -127,13 +180,11 @@ def ssd(x, dt, A, Bm, Cm, chunk: int = 128):
 
 @jax.custom_vjp
 def _swiglu_op(xf, wi, wg, wo):
-    t, d = xf.shape
-    bt = _blk(t, 256)
-    bf = _blk(wi.shape[1], 512)
+    bt, bf = swiglu_blocks(xf.shape[0], wi.shape[1], _compiled())
     if bt < 8 or bf < 8:
+        _fallback("swiglu")
         return _ref.swiglu_ref(xf, wi, wg, wo)
-    return _swiglu(xf, wi, wg, wo, block_t=bt, block_f=bf,
-                   interpret=INTERPRET)
+    return _swiglu(xf, wi, wg, wo, block_t=bt, block_f=bf)
 
 
 def _swiglu_fwd(xf, wi, wg, wo):

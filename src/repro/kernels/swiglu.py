@@ -5,7 +5,10 @@ leaving VMEM: grid (token_blocks, ff_blocks) with ff 'arbitrary'
 (sequential), accumulating the second matmul into a (block_t, d) f32
 scratch.  The VMEM working set is 2 weight panels + x/y blocks — the
 narrowing resource pre-check rejects configs whose panels exceed VMEM
-(exactly the FPGA FF/LUT rejection of the paper).
+(exactly the FPGA FF/LUT rejection of the paper).  ``vmem_bytes`` is
+that working set; the kernel asks the compiler for it explicitly, since
+the whole ``d`` sits in every block and published widths overflow the
+default scoped VMEM limit.
 """
 from __future__ import annotations
 
@@ -13,8 +16,25 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
+
+#: VMEM of one TPU v5e core, and the compiler's default scoped limit
+VMEM_CAPACITY = 128 * 2**20
+VMEM_DEFAULT_LIMIT = 16 * 2**20
+
+
+def vmem_bytes(block_t: int, block_f: int, d: int, x_itemsize: int,
+               w_itemsize: int) -> int:
+    """Bytes of VMEM the kernel holds at these blocks."""
+    io = 2 * 2 * block_t * d * x_itemsize       # x in + y out, 2 buffers
+    panels = 2 * 3 * d * block_f * w_itemsize   # wi, wg, wo, 2 buffers
+    acc = block_t * d * 4                       # f32 accumulator
+    temps = 3 * block_t * block_f * 4           # h, g, silu(g) * h in f32
+    return io + panels + acc + temps
 
 
 def _swiglu_kernel(x_ref, wi_ref, wg_ref, wo_ref, y_ref, acc_scr,
@@ -25,13 +45,18 @@ def _swiglu_kernel(x_ref, wi_ref, wg_ref, wo_ref, y_ref, acc_scr,
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    x = x_ref[...].astype(jnp.float32)                # (bt, d)
-    wi = wi_ref[...].astype(jnp.float32)              # (d, bf)
-    wg = wg_ref[...].astype(jnp.float32)
-    wo = wo_ref[...].astype(jnp.float32)              # (bf, d)
-    h = x @ wi
-    g = x @ wg
-    acc_scr[...] += (g * jax.nn.sigmoid(g) * h) @ wo
+    # operands enter the MXU in their own dtype, products accumulate in
+    # f32.  Sub-f32 operands multiply exactly in one pass, and Mosaic
+    # takes no f32 contract precision for them: keep a caller's
+    # "highest" for f32 operands only.
+    x = x_ref[...]                                    # (bt, d)
+    prec = None if x.dtype == jnp.float32 else lax.Precision.DEFAULT
+    dot = functools.partial(jnp.dot, precision=prec,
+                            preferred_element_type=jnp.float32)
+    h = dot(x, wi_ref[...])
+    g = dot(x, wg_ref[...])
+    act = (g * jax.nn.sigmoid(g) * h).astype(wo_ref.dtype)   # (bt, bf)
+    acc_scr[...] += dot(act, wo_ref[...])
 
     @pl.when(fb == n_ff_blocks - 1)
     def _out():
@@ -40,9 +65,10 @@ def _swiglu_kernel(x_ref, wi_ref, wg_ref, wo_ref, y_ref, acc_scr,
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_f",
                                              "interpret"))
-def swiglu_pallas(x, wi, wg, wo, block_t: int = 256, block_f: int = 512,
-                  interpret: bool = True):
+def swiglu_pallas(x, wi, wg, wo, block_t: int = 256, block_f: int = 256,
+                  interpret: bool | None = None):
     """x (T,d); wi,wg (d,f); wo (f,d) -> (T,d)."""
+    interpret = resolve_interpret(interpret)
     t, d = x.shape
     f = wi.shape[1]
     block_t = min(block_t, t)
@@ -55,6 +81,11 @@ def swiglu_pallas(x, wi, wg, wo, block_t: int = 256, block_f: int = 512,
     wo_spec = pl.BlockSpec((block_f, d), lambda tb, fb: (fb, 0))
     y_spec = pl.BlockSpec((block_t, d), lambda tb, fb: (tb, 0))
 
+    # the working set plus a quarter for Mosaic's own temporaries, never
+    # below the default limit and never above the core's VMEM
+    need = vmem_bytes(block_t, block_f, d, x.dtype.itemsize,
+                      wi.dtype.itemsize)
+    limit = min(max(need + need // 4, VMEM_DEFAULT_LIMIT), VMEM_CAPACITY)
     return pl.pallas_call(
         functools.partial(_swiglu_kernel, n_ff_blocks=grid[1]),
         grid=grid,
@@ -63,7 +94,7 @@ def swiglu_pallas(x, wi, wg, wo, block_t: int = 256, block_f: int = 512,
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=dict(mosaic=dict(
-            dimension_semantics=("parallel", "arbitrary")))
-        if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=limit),
     )(x, wi, wg, wo)

@@ -43,13 +43,18 @@ HOST_DEVICE_COUNT = 512
 
 
 def setup_host_devices(n: int = HOST_DEVICE_COUNT) -> None:
-    """Pin the placeholder host device count for this process.
+    """Pin this process to the CPU platform with ``n`` placeholder host
+    devices, keeping any other ``XLA_FLAGS`` it was given.
 
     Must run before jax's backend initializes (``main()`` calls it first
     thing; the CompiledBackend subprocess therefore gets 512 devices while
     in-process importers keep their real device count)."""
-    os.environ["XLA_FLAGS"] = \
-        f"--xla_force_host_platform_device_count={n}"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    flags = os.environ.get("XLA_FLAGS", "").split()
+    flags = [f for f in flags
+             if not f.startswith("--xla_force_host_platform_device_count")]
+    flags.append(f"--xla_force_host_platform_device_count={n}")
+    os.environ["XLA_FLAGS"] = " ".join(flags)
 
 
 def model_flops(cfg, shape) -> float:
@@ -73,14 +78,6 @@ def _mem_dict(mem) -> dict:
     if not out:
         out["repr"] = str(mem)
     return out
-
-
-def _cost_dict(cost) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions (older
-    ones return a per-device list of dicts, newer a single dict)."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost if isinstance(cost, dict) else {}
 
 
 def _clamp_microbatches(plan, shape, mesh) -> int:
@@ -271,7 +268,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 compiled = lowered.compile()
             with clock.stage("analyze"):
                 mem = compiled.memory_analysis()
-                cost = _cost_dict(compiled.cost_analysis())
+                cost = compiled.cost_analysis() or {}
                 hlo = compiled.as_text()
         census = collective_census(hlo)
         brep = batching_report(hlo)

@@ -41,9 +41,11 @@ from repro import obs
 from repro.configs import get_config
 from repro.core.adapt import ReconfigPolicy, Reconfigurator
 from repro.core.ga import GAConfig
+from repro.core.power import modeled_spec
 from repro.fleet import (AdmissionController, FleetPolicy, FleetPowerPlanner,
                          FleetScheduler, Node, PowerPlanPolicy, SegmentFleet,
                          VectorArrivals, VectorFleet, VectorNodeSpec)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serve.engine import Request
 from repro.telemetry import (GovernorPolicy, PowerGovernor, WsBudget,
@@ -257,7 +259,7 @@ def run_vector(args) -> None:
             print(f"profile {p}: {row['seconds']:.4f}s x{row['count']}")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny-test")
     ap.add_argument("--reduced", action="store_true")
@@ -364,6 +366,58 @@ def main() -> None:
                     help="persist the flight-recorder snapshot rows "
                          "(JSONL) here, rendered offline via "
                          "scripts/trace_report.py --flight")
+    return ap
+
+
+def build_fleet(args, cfg):
+    """The object engine's fleet: the model with random weights from seed
+    0, one ``Node`` per ``--fleet`` under a ``FleetScheduler``.  Returns
+    (nodes, scheduler, admission controller or None, planner or None)."""
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+
+    nodes = []
+    for i in range(max(args.fleet, 1)):
+        name = f"{args.node}{i}"
+        governor = build_governor(cfg, args, name) if args.govern else None
+        nodes.append(Node.build(name, model, params, slots=args.slots,
+                                max_seq=args.max_seq, governor=governor))
+    admission = None
+    if args.admission:
+        admission = AdmissionController(
+            parse_budgets(args.admission, args.admission_window))
+    planner = None
+    if args.placement:
+        planner = FleetPowerPlanner(policy=PowerPlanPolicy(
+            mode=args.placement, slo_queue_depth=args.slo_queue_depth))
+    sched = FleetScheduler(
+        nodes,
+        policy=FleetPolicy(flush_every=args.flush_every,
+                           checkpoint_every=args.checkpoint_every,
+                           router=args.router,
+                           migrate_on_drift=not args.no_drain),
+        admission=admission, planner=planner)
+    return nodes, sched, admission, planner
+
+
+def request_maker(args, cfg, prompt_len: tuple = (4, 12)):
+    """``make_request(i)``: request i with a random prompt of
+    ``prompt_len`` [low, high) tokens from seed 0, tenants cycled."""
+    tenants = [t.strip() for t in args.tenants.split(",") if t.strip()] \
+        or ["default"]
+    rng = np.random.default_rng(0)
+
+    def make_request(i: int) -> Request:
+        plen = int(rng.integers(*prompt_len))
+        prompt = rng.integers(2, cfg.vocab_size, size=plen).astype(np.int32)
+        return Request(rid=i, prompt=prompt, max_new=args.max_new,
+                       tenant=tenants[i % len(tenants)])
+    return make_request
+
+
+def main() -> None:
+    enable_compile_cache()
+    ap = build_parser()
     args = ap.parse_args()
 
     if args.engine != "object":
@@ -393,40 +447,11 @@ def main() -> None:
         return
 
     cfg = get_config(args.arch, reduced=args.reduced)
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-
-    nodes = []
-    for i in range(max(args.fleet, 1)):
-        name = f"{args.node}{i}"
-        governor = build_governor(cfg, args, name) if args.govern else None
-        nodes.append(Node.build(name, model, params, slots=args.slots,
-                                max_seq=args.max_seq, governor=governor))
-    admission = None
-    if args.admission:
-        admission = AdmissionController(
-            parse_budgets(args.admission, args.admission_window))
-    planner = None
-    if args.placement:
-        planner = FleetPowerPlanner(policy=PowerPlanPolicy(
-            mode=args.placement, slo_queue_depth=args.slo_queue_depth))
-    sched = FleetScheduler(
-        nodes,
-        policy=FleetPolicy(flush_every=args.flush_every,
-                           checkpoint_every=args.checkpoint_every,
-                           router=args.router,
-                           migrate_on_drift=not args.no_drain),
-        admission=admission, planner=planner)
-
-    tenants = [t.strip() for t in args.tenants.split(",") if t.strip()] \
-        or ["default"]
-    rng = np.random.default_rng(0)
-
-    def make_request(i: int) -> Request:
-        plen = int(rng.integers(4, 12))
-        prompt = rng.integers(2, cfg.vocab_size, size=plen).astype(np.int32)
-        return Request(rid=i, prompt=prompt, max_new=args.max_new,
-                       tenant=tenants[i % len(tenants)])
+    nodes, sched, admission, planner = build_fleet(args, cfg)
+    make_request = request_maker(args, cfg)
+    dev = jax.devices()[0]
+    print(f"energy: modeled {modeled_spec(dev).name} watts x measured "
+          f"seconds, serving on {dev.platform} ({dev.device_kind})")
 
     t0 = time.time()
     if args.diurnal:

@@ -19,11 +19,13 @@ import jax
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig
 from repro.ft.driver import FailureInjector, TrainDriver
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.train.step import make_opt_init, make_train_step
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny-lm")
     ap.add_argument("--reduced", action="store_true",

@@ -89,9 +89,12 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int):
         # intra-chunk (dual quadratic form)
         cb = jnp.einsum("bsn,brn->bsr", cc.astype(jnp.float32),
                         bc.astype(jnp.float32))            # (B,Q,Q)
-        decay = jnp.exp(cum[:, :, None, :] - cum[:, None, :, :])  # (B,Q,Q,H)
-        tri = jnp.tril(jnp.ones((q, q), jnp.float32))
-        w = cb[..., None] * decay * tri[None, :, :, None]  # (B,Q,Q,H)
+        # mask before exp: above the diagonal the segment sum is positive
+        # and overflows at long chunks (inf * 0 would poison the sum)
+        tri = jnp.tril(jnp.ones((q, q), bool))[None, :, :, None]
+        decay = jnp.exp(jnp.where(tri, cum[:, :, None, :] - cum[:, None, :, :],
+                                  -jnp.inf))               # (B,Q,Q,H)
+        w = cb[..., None] * decay                          # (B,Q,Q,H)
         y_intra = jnp.einsum("bsrh,brhp->bshp", w, xdc.astype(jnp.float32))
         # contribution of the carried state
         y_inter = jnp.einsum("bsn,bhpn,bsh->bshp",

@@ -141,8 +141,11 @@ def init_params(key, cfg: ArchConfig):
                 for i, kind in enumerate(unit)}
 
     if n_full:
-        trees = [unit_params(k) for k in jax.random.split(k_scan, n_full)]
-        params["scan"] = jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+        # one vmapped init per unit, born stacked: the same values as
+        # stacking per-layer inits, without a second copy of every layer
+        # and with a program whose size does not grow with depth
+        params["scan"] = jax.vmap(unit_params)(
+            jax.random.split(k_scan, n_full))
     if tail:
         ks = jax.random.split(k_tail, len(tail))
         params["tail"] = {f"t{i}": init_layer(ks[i], cfg, kind)

@@ -244,6 +244,10 @@ class ServeLoop:
                 for t, tok in enumerate(seq[:-1]):
                     self._step_one(i, int(tok), t)
                 if self.meter is not None:
+                    # dispatch is asynchronous: wait for the prefill to
+                    # finish on the device, or its seconds (and Ws) slide
+                    # into the next decode step
+                    jax.block_until_ready(self.cache)
                     dt = self.clock() - t0
                     util = 1.0 / self.slots
                     self._record_util("prefill", dt, util)

@@ -146,6 +146,17 @@ def test_compiled_rung_via_stubbed_subprocess(tmp_path):
     assert mr.utilization == pytest.approx(m.utilization)
 
 
+def test_compiled_rung_child_is_pinned_to_cpu(tmp_path, monkeypatch):
+    """The dry-run child compiles for placeholder host devices; it must
+    never reach for an accelerator its parent may hold."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    seen = {}
+    backend = CompiledBackend(art_dir=tmp_path)
+    backend.runner = lambda cmd, **kw: seen.update(kw["env"])
+    backend.measure(_ctx(), get_config("tiny-test").plan)
+    assert seen["JAX_PLATFORMS"] == "cpu"
+
+
 @pytest.mark.parametrize("record,sidecar", [
     (None, None),                                # nothing produced
     ("{not json", None),                         # malformed record

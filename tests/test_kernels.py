@@ -162,3 +162,20 @@ def test_swiglu_blocks(t, d, f, bt, bf):
     y = swiglu_pallas(x, wi, wg, wo, block_t=bt, block_f=bf)
     y0 = ref.swiglu_ref(x, wi, wg, wo)
     np.testing.assert_allclose(y, y0, atol=2e-5, rtol=2e-5)
+
+
+def test_ssd_long_chunk_stays_finite():
+    """At a published chunk length the segment sums above the diagonal
+    overflow exp; the oracle and the kernel must mask them first."""
+    k = _keys(5)
+    b, s, h, p, n = 1, 256, 2, 8, 4
+    x = jax.random.normal(k[0], (b, s, h, p))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (b, s, h)))   # sum >> 88
+    A = -jnp.ones((h,))
+    Bm = jax.random.normal(k[3], (b, s, n))
+    Cm = jax.random.normal(k[4], (b, s, n))
+    y0, hs0 = ref.ssd_ref(x, dt, A, Bm, Cm, chunk=s)
+    assert bool(jnp.isfinite(y0).all() and jnp.isfinite(hs0).all())
+    y, hs = ssd_pallas(x, dt, A, Bm, Cm, chunk=s)
+    np.testing.assert_allclose(y, y0, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(hs, hs0, atol=1e-4, rtol=1e-4)
