@@ -1,0 +1,13 @@
+"""Share of its roofline that the ssd kernel reaches in the traced
+slice (the shared reduction in `bench/roofline.py`, with the kernel's
+work count in `bench/kernels/ssd.py`)."""
+from roofline import kernel_roofline
+
+NAME = "ssd_roofline"
+UNIT = "%"
+LAYER = "kernels"
+MOVES = "output_tokens_per_s"
+
+
+def read(ctx):
+    return kernel_roofline(ctx, "ssd")

@@ -1,0 +1,9 @@
+"""The benchmark's modules import each other by their plain names, as
+`bench/run.py` arranges; the tests arrange the same."""
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+for p in (BENCH.parent / "src", BENCH):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
