@@ -109,6 +109,7 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                         pltpu.VMEM((block_q,), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),

@@ -65,6 +65,7 @@ def mriq_pallas(kx, ky, kz, phi_mag, x, y, z,
         out_specs=[vox_spec, vox_spec],
         out_shape=[jax.ShapeDtypeStruct((1, n), jnp.float32)] * 2,
         interpret=interpret,
+        name="mriq_pallas",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(*(a.reshape(1, n) for a in (x, y, z)),

@@ -60,6 +60,7 @@ def rglru_pallas(log_a, b, block_w: int = 512, block_t: int = 128,
         out_shape=jax.ShapeDtypeStruct((bsz, s, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
+        name="rglru_pallas",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(log_a, b)

@@ -110,6 +110,7 @@ def ssd_pallas(x, dt, A, Bm, Cm, chunk: int = 128,
                    jax.ShapeDtypeStruct((b, h, p, n), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_pallas",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(xd, cum[..., None], cum[:, :, None, :], Bm, Cm)

@@ -94,6 +94,7 @@ def swiglu_pallas(x, wi, wg, wo, block_t: int = 256, block_f: int = 256,
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)],
         interpret=interpret,
+        name="swiglu_pallas",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=limit),

@@ -28,6 +28,11 @@ def enable_compile_cache() -> str:
     # starts cold, and the eager ops of a model's init alone add up to
     # tens of seconds of compiling on the chip
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # key on the program's metadata too: jax strips it from the key by
+    # default, so a program that differs from a cached one only in its
+    # named scopes would load the cached executable, whose device ops
+    # then carry the old scopes (or none) in a profiler trace
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

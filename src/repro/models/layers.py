@@ -220,24 +220,27 @@ def run_attention(params, x, cfg: ArchConfig, plan: PlanConfig, positions,
         t = ck.shape[1]
         pos = positions[0]
         slot = lax.rem(pos, t)
+        with jax.named_scope("kv_write"):
+            if int8_cache:
+                kq, ks = _kv_quant(k)
+                vq, vs = _kv_quant(v)
+                ck = lax.dynamic_update_slice(ck, kq, (0, slot, 0, 0))
+                cv = lax.dynamic_update_slice(cv, vq, (0, slot, 0, 0))
+                k_sc = lax.dynamic_update_slice(cache["k_scale"], ks,
+                                                (0, slot, 0, 0))
+                v_sc = lax.dynamic_update_slice(cache["v_scale"], vs,
+                                                (0, slot, 0, 0))
+            else:
+                ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
+                                              (0, slot, 0, 0))
+                cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
+                                              (0, slot, 0, 0))
+            kpos = lax.dynamic_update_slice(kpos, pos[None], (slot,))
         if int8_cache:
-            kq, ks = _kv_quant(k)
-            vq, vs = _kv_quant(v)
-            ck = lax.dynamic_update_slice(ck, kq, (0, slot, 0, 0))
-            cv = lax.dynamic_update_slice(cv, vq, (0, slot, 0, 0))
-            k_sc = lax.dynamic_update_slice(cache["k_scale"], ks,
-                                            (0, slot, 0, 0))
-            v_sc = lax.dynamic_update_slice(cache["v_scale"], vs,
-                                            (0, slot, 0, 0))
             kk = _kv_dequant(ck, k_sc, q.dtype)
             vv = _kv_dequant(cv, v_sc, q.dtype)
         else:
-            ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, slot, 0, 0))
-            cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, slot, 0, 0))
             kk, vv = ck.astype(q.dtype), cv.astype(q.dtype)
-        kpos = lax.dynamic_update_slice(kpos, pos[None], (slot,))
         valid = (kpos >= 0) & (kpos <= pos)
         kpos_m = jnp.where(valid, kpos, pos + t + 10)  # fails causal rule
         qpos = jnp.full((q.shape[1],), pos)
@@ -263,29 +266,30 @@ def run_attention(params, x, cfg: ArchConfig, plan: PlanConfig, positions,
             o = attention_naive(q, k, v, qpos, kpos, causal, window)
         new_cache = None
         if cache is not None:  # prefill: keep the last T positions
-            t = cache["k"].shape[1]
-            s = k.shape[1]
-            ktail, vtail = k[:, -t:], v[:, -t:]
-            tailpos = jnp.arange(max(s - t, 0), s, dtype=jnp.int32)
-            slots = tailpos % t
-            if int8_cache:
-                kq, ks = _kv_quant(ktail)
-                vq, vs = _kv_quant(vtail)
-                new_cache = {
-                    "k": cache["k"].at[:, slots].set(kq),
-                    "v": cache["v"].at[:, slots].set(vq),
-                    "k_scale": cache["k_scale"].at[:, slots].set(ks),
-                    "v_scale": cache["v_scale"].at[:, slots].set(vs),
-                    "kpos": cache["kpos"].at[slots].set(tailpos),
-                }
-            else:
-                new_cache = {
-                    "k": cache["k"].at[:, slots].set(
-                        ktail.astype(cache["k"].dtype)),
-                    "v": cache["v"].at[:, slots].set(
-                        vtail.astype(cache["v"].dtype)),
-                    "kpos": cache["kpos"].at[slots].set(tailpos),
-                }
+            with jax.named_scope("kv_write"):
+                t = cache["k"].shape[1]
+                s = k.shape[1]
+                ktail, vtail = k[:, -t:], v[:, -t:]
+                tailpos = jnp.arange(max(s - t, 0), s, dtype=jnp.int32)
+                slots = tailpos % t
+                if int8_cache:
+                    kq, ks = _kv_quant(ktail)
+                    vq, vs = _kv_quant(vtail)
+                    new_cache = {
+                        "k": cache["k"].at[:, slots].set(kq),
+                        "v": cache["v"].at[:, slots].set(vq),
+                        "k_scale": cache["k_scale"].at[:, slots].set(ks),
+                        "v_scale": cache["v_scale"].at[:, slots].set(vs),
+                        "kpos": cache["kpos"].at[slots].set(tailpos),
+                    }
+                else:
+                    new_cache = {
+                        "k": cache["k"].at[:, slots].set(
+                            ktail.astype(cache["k"].dtype)),
+                        "v": cache["v"].at[:, slots].set(
+                            vtail.astype(cache["v"].dtype)),
+                        "kpos": cache["kpos"].at[slots].set(tailpos),
+                    }
 
     y = jnp.einsum("bshk,hkd->bsd", o, params["wo"].astype(o.dtype))
     return y, new_cache

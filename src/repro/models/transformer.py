@@ -66,14 +66,17 @@ def apply_layer(params, x, kind: str, cfg: ArchConfig, plan: PlanConfig,
     h = L.apply_norm(params["norm1"], x, cfg)
     if kind == "attn":
         window = cfg.local_window if cfg.family == "hybrid" else 0
-        mix, new_cache = L.run_attention(params["mixer"], h, cfg, plan,
-                                         positions, cache, decode, window)
+        with jax.named_scope("attn"):
+            mix, new_cache = L.run_attention(params["mixer"], h, cfg, plan,
+                                             positions, cache, decode, window)
     elif kind == "rec":
-        mix, new_cache = R.run_rglru_block(params["mixer"], h, cfg, plan,
-                                           cache, decode)
+        with jax.named_scope("rec"):
+            mix, new_cache = R.run_rglru_block(params["mixer"], h, cfg, plan,
+                                               cache, decode)
     elif kind == "ssm":
-        mix, new_cache = S.run_mamba2(params["mixer"], h, cfg, plan,
-                                      cache, decode)
+        with jax.named_scope("ssm"):
+            mix, new_cache = S.run_mamba2(params["mixer"], h, cfg, plan,
+                                          cache, decode)
         x = x + mix
         if rules is not None:
             x = constrain(x, rules, "batch", "seq_sharded", "act_embed")
@@ -83,9 +86,11 @@ def apply_layer(params, x, kind: str, cfg: ArchConfig, plan: PlanConfig,
     x = x + mix
     h = L.apply_norm(params["norm2"], x, cfg)
     if "moe" in params:
-        ff, aux = L.run_moe(params["moe"], h, cfg, plan)
+        with jax.named_scope("moe"):
+            ff, aux = L.run_moe(params["moe"], h, cfg, plan)
     else:
-        ff = L.run_mlp(params["mlp"], h, cfg, plan)
+        with jax.named_scope("mlp"):
+            ff = L.run_mlp(params["mlp"], h, cfg, plan)
     x = x + ff
     if rules is not None:
         x = constrain(x, rules, "batch", "seq_sharded", "act_embed")
@@ -217,7 +222,8 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: PlanConfig,
     decode:  cache=tree, decode=True   -> logits (B,1,V) + updated cache
     """
     unit, n_full, tail = unit_structure(cfg)
-    h = embed_inputs(params, batch, cfg, plan, rules)
+    with jax.named_scope("embed"):
+        h = embed_inputs(params, batch, cfg, plan, rules)
     b, s = h.shape[0], h.shape[1]
 
     if decode:
@@ -236,8 +242,9 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: PlanConfig,
         ncache = {}
         for i, kind in enumerate(unit):
             c = ucache.get(f"l{i}") if ucache is not None else None
-            hh, nc, a = apply_layer(uparams[f"l{i}"], hh, kind, cfg, plan,
-                                    positions, c, decode, rules)
+            with jax.named_scope("layer"):
+                hh, nc, a = apply_layer(uparams[f"l{i}"], hh, kind, cfg,
+                                        plan, positions, c, decode, rules)
             aux = aux + a
             if nc is not None:
                 ncache[f"l{i}"] = nc
@@ -245,37 +252,44 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: PlanConfig,
 
     body = _remat_wrap(unit_body, plan)
 
-    if n_full:
-        if plan.scan_layers:
-            xs = (params["scan"], cache.get("scan") if cache else None)
-            (h, aux_total), scan_cache = lax.scan(body, (h, aux_total), xs)
-            if cache is not None:
-                new_cache["scan"] = scan_cache
-        else:
-            sp = params["scan"]
-            for li in range(n_full):
-                up = jax.tree.map(lambda a, li=li: a[li], sp)
-                uc = (jax.tree.map(lambda a, li=li: a[li], cache["scan"])
-                      if cache else None)
-                (h, aux_total), nc = body((h, aux_total), (up, uc))
+    # the scan's slicing of each layer's weights and cache, and its
+    # write-back, land in `layers`; the layers' own ops in `layer`
+    with jax.named_scope("layers"):
+        if n_full:
+            if plan.scan_layers:
+                xs = (params["scan"], cache.get("scan") if cache else None)
+                (h, aux_total), scan_cache = lax.scan(body, (h, aux_total), xs)
                 if cache is not None:
-                    new_cache.setdefault("_scan_list", []).append(nc)
-            if cache is not None:
-                ncs = new_cache.pop("_scan_list")
-                new_cache["scan"] = jax.tree.map(lambda *xs: jnp.stack(xs), *ncs)
+                    new_cache["scan"] = scan_cache
+            else:
+                sp = params["scan"]
+                for li in range(n_full):
+                    up = jax.tree.map(lambda a, li=li: a[li], sp)
+                    uc = (jax.tree.map(lambda a, li=li: a[li], cache["scan"])
+                          if cache else None)
+                    (h, aux_total), nc = body((h, aux_total), (up, uc))
+                    if cache is not None:
+                        new_cache.setdefault("_scan_list", []).append(nc)
+                if cache is not None:
+                    ncs = new_cache.pop("_scan_list")
+                    new_cache["scan"] = jax.tree.map(
+                        lambda *xs: jnp.stack(xs), *ncs)
 
-    for i, kind in enumerate(tail):
-        c = cache["tail"][f"t{i}"] if cache else None
-        h, nc, a = apply_layer(params["tail"][f"t{i}"], h, kind, cfg, plan,
-                               positions, c, decode, rules)
-        aux_total = aux_total + a
-        if nc is not None:
-            new_cache.setdefault("tail", {})[f"t{i}"] = nc
+        for i, kind in enumerate(tail):
+            c = cache["tail"][f"t{i}"] if cache else None
+            with jax.named_scope("layer"):
+                h, nc, a = apply_layer(params["tail"][f"t{i}"], h, kind, cfg,
+                                       plan, positions, c, decode, rules)
+            aux_total = aux_total + a
+            if nc is not None:
+                new_cache.setdefault("tail", {})[f"t{i}"] = nc
 
-    h = L.apply_norm(params["final_norm"], h, cfg)
-    wout = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", h, wout.astype(h.dtype))
-    if rules is not None:
-        # vocab gets the model axis (loss reductions stay sharded)
-        logits = constrain(logits, rules, "batch", None, "vocab")
+    with jax.named_scope("head"):
+        h = L.apply_norm(params["final_norm"], h, cfg)
+        wout = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.einsum("bsd,dv->bsv", h, wout.astype(h.dtype))
+        if rules is not None:
+            # vocab gets the model axis (loss reductions stay sharded)
+            logits = constrain(logits, rules, "batch", None, "vocab")
     return logits, (new_cache if cache is not None else None), aux_total

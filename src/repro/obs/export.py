@@ -14,7 +14,7 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-from repro.obs.span import Span, load_spans_jsonl
+from repro.obs.span import Span
 
 
 def write_spans_jsonl(spans: list, path) -> str:
@@ -27,7 +27,14 @@ def write_spans_jsonl(spans: list, path) -> str:
 
 
 def read_spans_jsonl(path) -> list:
-    return load_spans_jsonl(path)
+    """Read a spans JSONL file back (inverse of ``write_spans_jsonl`` and
+    ``Tracer.to_jsonl``)."""
+    spans = []
+    for line in Path(path).read_text().splitlines():
+        line = line.strip()
+        if line:
+            spans.append(Span.from_dict(json.loads(line)))
+    return spans
 
 
 def chrome_trace_events(spans: list) -> list:

@@ -23,7 +23,6 @@ pays one attribute check when tracing is off.  Dependency- and jax-free.
 """
 from __future__ import annotations
 
-import json
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -199,13 +198,3 @@ class NullTracer:
     def to_jsonl(self, path) -> str:
         Path(path).write_text("")
         return str(path)
-
-
-def load_spans_jsonl(path) -> list[Span]:
-    """Read a spans JSONL file back (inverse of ``Tracer.to_jsonl``)."""
-    spans = []
-    for line in Path(path).read_text().splitlines():
-        line = line.strip()
-        if line:
-            spans.append(Span.from_dict(json.loads(line)))
-    return spans

@@ -91,6 +91,41 @@ def test_compile_cache_dir(monkeypatch):
                           min_t)
 
 
+def _cache_key(scope: str) -> str:
+    """The persistent cache's key of a program whose one op sits in the
+    named scope `scope`."""
+    import numpy as np
+    from jax._src import cache_key, compiler, xla_bridge
+
+    def f(x):
+        with jax.named_scope(scope):
+            return jnp.sin(x)
+    ir = jax.jit(f).lower(jnp.ones(8)).compiler_ir("stablehlo")
+    opts = compiler.get_compile_options(num_replicas=1, num_partitions=1)
+    return cache_key.get(ir, np.array([jax.devices()[0]]), opts,
+                         xla_bridge.get_backend())
+
+
+def test_compile_cache_keys_on_the_named_scopes(monkeypatch):
+    """A program that differs from a cached one only in its scopes is not
+    read back as that one: its device ops would carry the old names."""
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    min_t = jax.config.jax_persistent_cache_min_compile_time_secs
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    try:
+        jax.config.update(flag, False)
+        assert len({_cache_key(s) for s in ("layers", "layer")}) == 1
+        compile_cache.enable_compile_cache()
+        assert getattr(jax.config, flag) is True
+        keys = [_cache_key(s) for s in ("layers", "layers", "layer")]
+        assert keys[0] == keys[1] != keys[2]
+    finally:
+        jax.config.update(flag, before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_t)
+
+
 def test_dryrun_child_pins_cpu_and_keeps_xla_flags(monkeypatch):
     monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=/x "
                        "--xla_force_host_platform_device_count=4")
