@@ -178,31 +178,51 @@ def ssd(x, dt, A, Bm, Cm, chunk: int = 128):
     return _ssd_op(x, dt, A, Bm, Cm, chunk)
 
 
+def _layer_panels(wi, wg, wo, layer):
+    """The (d,f), (d,f), (f,d) panels of one layer: the weights themselves,
+    or layer ``layer`` of stacked weights."""
+    if layer is None:
+        return wi, wg, wo
+    return wi[layer], wg[layer], wo[layer]
+
+
 @jax.custom_vjp
-def _swiglu_op(xf, wi, wg, wo):
-    bt, bf = swiglu_blocks(xf.shape[0], wi.shape[1], _compiled())
+def _swiglu_op(xf, wi, wg, wo, layer):
+    bt, bf = swiglu_blocks(xf.shape[0], wi.shape[-1], _compiled())
     if bt < 8 or bf < 8:
         _fallback("swiglu")
-        return _ref.swiglu_ref(xf, wi, wg, wo)
-    return _swiglu(xf, wi, wg, wo, block_t=bt, block_f=bf)
+        return _ref.swiglu_ref(xf, *_layer_panels(wi, wg, wo, layer))
+    if layer is not None:
+        obs.METRICS.counter(
+            "kernel_stacked_swiglu",
+            "traces whose swiglu read its panels from the stacked weights "
+            "by layer index").inc()
+    return _swiglu(xf, wi, wg, wo, layer, block_t=bt, block_f=bf)
 
 
-def _swiglu_fwd(xf, wi, wg, wo):
-    return _swiglu_op(xf, wi, wg, wo), (xf, wi, wg, wo)
+def _swiglu_fwd(xf, wi, wg, wo, layer):
+    return _swiglu_op(xf, wi, wg, wo, layer), (xf, wi, wg, wo, layer)
 
 
 def _swiglu_bwd(res, g):
-    _, vjp = jax.vjp(_ref.swiglu_ref, *res)
-    return vjp(g)
+    xf, wi, wg, wo, layer = res
+    _, vjp = jax.vjp(lambda x, *w: _ref.swiglu_ref(
+        x, *_layer_panels(*w, layer)), xf, wi, wg, wo)
+    return (*vjp(g), None)
 
 
 _swiglu_op.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
-def fused_swiglu(x, wi, wg, wo):
-    """x (..., d) -> (..., d); flattens leading dims for the kernel."""
+def fused_swiglu(x, wi, wg, wo, layer=None):
+    """x (..., d) -> (..., d); flattens leading dims for the kernel.
+
+    With ``layer`` (an integer scalar) wi, wg, wo are stacked per layer,
+    (L,d,f), (L,d,f), (L,f,d), and layer ``layer`` runs: its panels are
+    read where they lie in the stacks, with no slice of them made.
+    """
     lead = x.shape[:-1]
     d = x.shape[-1]
     t = math.prod(lead)
-    y = _swiglu_op(x.reshape(t, d), wi, wg, wo)
+    y = _swiglu_op(x.reshape(t, d), wi, wg, wo, layer)
     return y.reshape(*lead, d)

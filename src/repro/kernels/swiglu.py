@@ -63,39 +63,66 @@ def _swiglu_kernel(x_ref, wi_ref, wg_ref, wo_ref, y_ref, acc_scr,
         y_ref[...] = acc_scr[...].astype(y_ref.dtype)
 
 
+def _stacked_kernel(layer_ref, *refs, n_ff_blocks: int):
+    # the layer index is used by the index maps alone
+    del layer_ref
+    _swiglu_kernel(*refs, n_ff_blocks=n_ff_blocks)
+
+
 @functools.partial(jax.jit, static_argnames=("block_t", "block_f",
                                              "interpret"))
-def swiglu_pallas(x, wi, wg, wo, block_t: int = 256, block_f: int = 256,
-                  interpret: bool | None = None):
-    """x (T,d); wi,wg (d,f); wo (f,d) -> (T,d)."""
+def swiglu_pallas(x, wi, wg, wo, layer=None, block_t: int = 256,
+                  block_f: int = 256, interpret: bool | None = None):
+    """x (T,d); wi,wg (d,f); wo (f,d) -> (T,d).
+
+    With ``layer`` (an integer scalar) the weights are stacks, wi,wg
+    (L,d,f) and wo (L,f,d), and the kernel DMAs layer ``layer``'s panels
+    straight out of them: the caller slices and copies nothing.
+    """
     interpret = resolve_interpret(interpret)
     t, d = x.shape
-    f = wi.shape[1]
+    f = wi.shape[-1]
     block_t = min(block_t, t)
     block_f = min(block_f, f)
     assert t % block_t == 0 and f % block_f == 0
     grid = (t // block_t, f // block_f)
 
-    x_spec = pl.BlockSpec((block_t, d), lambda tb, fb: (tb, 0))
-    wi_spec = pl.BlockSpec((d, block_f), lambda tb, fb: (0, fb))
-    wo_spec = pl.BlockSpec((block_f, d), lambda tb, fb: (fb, 0))
-    y_spec = pl.BlockSpec((block_t, d), lambda tb, fb: (tb, 0))
+    # the index maps take the prefetched layer index, where there is one,
+    # after the grid indices
+    x_spec = pl.BlockSpec((block_t, d), lambda tb, fb, *_: (tb, 0))
+    y_spec = pl.BlockSpec((block_t, d), lambda tb, fb, *_: (tb, 0))
+    if layer is None:
+        wi_spec = pl.BlockSpec((d, block_f), lambda tb, fb: (0, fb))
+        wo_spec = pl.BlockSpec((block_f, d), lambda tb, fb: (fb, 0))
+    else:
+        # the layer dimension is squeezed away: the kernel sees the same
+        # (d, block_f) / (block_f, d) panels as from unstacked weights
+        wi_spec = pl.BlockSpec((None, d, block_f),
+                               lambda tb, fb, l: (l[0], 0, fb))
+        wo_spec = pl.BlockSpec((None, block_f, d),
+                               lambda tb, fb, l: (l[0], fb, 0))
 
     # the working set plus a quarter for Mosaic's own temporaries, never
     # below the default limit and never above the core's VMEM
     need = vmem_bytes(block_t, block_f, d, x.dtype.itemsize,
                       wi.dtype.itemsize)
     limit = min(max(need + need // 4, VMEM_DEFAULT_LIMIT), VMEM_CAPACITY)
+    specs = dict(grid=grid, in_specs=[x_spec, wi_spec, wi_spec, wo_spec],
+                 out_specs=y_spec,
+                 scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)])
+    kernel, prefetch = _swiglu_kernel, ()
+    if layer is not None:
+        specs = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **specs))
+        kernel = _stacked_kernel
+        prefetch = (jnp.reshape(layer, (1,)).astype(jnp.int32),)
     return pl.pallas_call(
-        functools.partial(_swiglu_kernel, n_ff_blocks=grid[1]),
-        grid=grid,
-        in_specs=[x_spec, wi_spec, wi_spec, wo_spec],
-        out_specs=y_spec,
+        functools.partial(kernel, n_ff_blocks=grid[1]),
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
-        scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)],
         interpret=interpret,
         name="swiglu_pallas",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=limit),
-    )(x, wi, wg, wo)
+        **specs,
+    )(*prefetch, x, wi, wg, wo)

@@ -319,8 +319,15 @@ def init_mlp(key, cfg: ArchConfig, d_ff: Optional[int] = None):
     }
 
 
-def run_mlp(params, x, cfg: ArchConfig, plan: PlanConfig):
+def run_mlp(params, x, cfg: ArchConfig, plan: PlanConfig, layer=None):
+    """With ``layer`` (an integer scalar) the swiglu weights are stacked
+    per layer, (L,d,f) and (L,f,d), in the compute dtype, and the Pallas
+    kernel runs their layer ``layer`` where it lies in the stacks."""
     dt = cdtype(plan)
+    if layer is not None:
+        from repro.kernels import ops as kops
+        return kops.fused_swiglu(x, params["wi"], params["wg"], params["wo"],
+                                 layer)
     if cfg.act == "swiglu":
         if plan.mlp_impl == "pallas":
             from repro.kernels import ops as kops

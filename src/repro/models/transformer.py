@@ -60,8 +60,10 @@ def init_layer(key, cfg: ArchConfig, kind: str):
 
 def apply_layer(params, x, kind: str, cfg: ArchConfig, plan: PlanConfig,
                 positions, cache, decode: bool,
-                rules: Optional[ShardingRules]):
-    """Returns (x, new_cache, aux_loss)."""
+                rules: Optional[ShardingRules], mlp_layer=None):
+    """Returns (x, new_cache, aux_loss).  With ``mlp_layer`` the layer's
+    ``mlp`` weights are the scan's stacks and ``mlp_layer`` its index in
+    them (`stacked_mlps`)."""
     aux = jnp.zeros((), jnp.float32)
     h = L.apply_norm(params["norm1"], x, cfg)
     if kind == "attn":
@@ -90,7 +92,7 @@ def apply_layer(params, x, kind: str, cfg: ArchConfig, plan: PlanConfig,
             ff, aux = L.run_moe(params["moe"], h, cfg, plan)
     else:
         with jax.named_scope("mlp"):
-            ff = L.run_mlp(params["mlp"], h, cfg, plan)
+            ff = L.run_mlp(params["mlp"], h, cfg, plan, mlp_layer)
     x = x + ff
     if rules is not None:
         x = constrain(x, rules, "batch", "seq_sharded", "act_embed")
@@ -189,6 +191,26 @@ def _remat_wrap(fn, plan: PlanConfig):
     return jax.checkpoint(fn)
 
 
+def stacked_mlps(sp, cfg: ArchConfig, plan: PlanConfig, cache,
+                 rules: Optional[ShardingRules]) -> dict:
+    """The unit's MLP weight stacks, by unit slot, that the swiglu kernel
+    reads by layer index; empty where the scan slices each layer's MLP
+    weights out of ``sp`` (the stacked unit params) instead.
+
+    A custom call takes each operand whole, so a sliced panel is a copy
+    of it.  Serving calls (``cache`` given) on one device (no ``rules``)
+    with the Pallas swiglu MLP take the stacks, when the weights are
+    stored in the compute dtype: a cast would copy a whole stack.
+    Training keeps the slices, whose backward gives per-layer gradients
+    with no scatter into a whole stack.
+    """
+    if (cache is None or rules is not None or cfg.act != "swiglu"
+            or plan.mlp_impl != "pallas"
+            or L.pdtype(plan) != L.cdtype(plan)):
+        return {}
+    return {k: p["mlp"] for k, p in sp.items() if "mlp" in p}
+
+
 def embed_inputs(params, batch: dict, cfg: ArchConfig, plan: PlanConfig,
                  rules=None):
     dt = L.cdtype(plan)
@@ -236,15 +258,22 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: PlanConfig,
     aux_total = jnp.zeros((), jnp.float32)
     new_cache: dict[str, Any] = {}
 
+    stacks = (stacked_mlps(params["scan"], cfg, plan, cache, rules)
+              if n_full and plan.scan_layers else {})
+
     def unit_body(carry, xs):
         hh, aux = carry
-        uparams, ucache = xs
+        uparams, ucache = xs[:2]
+        li = xs[2] if stacks else None      # the layer's index in `stacks`
         ncache = {}
         for i, kind in enumerate(unit):
             c = ucache.get(f"l{i}") if ucache is not None else None
+            lp = uparams[f"l{i}"]
+            if f"l{i}" in stacks:
+                lp = {**lp, "mlp": stacks[f"l{i}"]}
             with jax.named_scope("layer"):
-                hh, nc, a = apply_layer(uparams[f"l{i}"], hh, kind, cfg,
-                                        plan, positions, c, decode, rules)
+                hh, nc, a = apply_layer(lp, hh, kind, cfg, plan, positions,
+                                        c, decode, rules, li)
             aux = aux + a
             if nc is not None:
                 ncache[f"l{i}"] = nc
@@ -258,6 +287,11 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: PlanConfig,
         if n_full:
             if plan.scan_layers:
                 xs = (params["scan"], cache.get("scan") if cache else None)
+                if stacks:
+                    # the MLP weights stay out of the scanned slices
+                    sp = {k: {n: w for n, w in p.items() if n != "mlp"}
+                          for k, p in params["scan"].items()}
+                    xs = (sp, xs[1], jnp.arange(n_full))
                 (h, aux_total), scan_cache = lax.scan(body, (h, aux_total), xs)
                 if cache is not None:
                     new_cache["scan"] = scan_cache
