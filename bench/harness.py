@@ -16,8 +16,9 @@ from __future__ import annotations
 
 import gc
 import time
-from dataclasses import dataclass, field
-from typing import Callable, Optional
+from dataclasses import dataclass, field, is_dataclass
+from typing import (Callable, Optional, get_args, get_origin,
+                    get_type_hints)
 
 import jax
 import jax.numpy as jnp
@@ -31,10 +32,33 @@ TRACE_DECODE_STEPS = 64
 
 def build_model(config: dict):
     """The program's `Model` at the configuration's sizes and pinned plan."""
-    from repro.configs.base import ArchConfig, PlanConfig
+    from repro.configs.base import ArchConfig
     from repro.models.model import Model
-    plan = PlanConfig(**config["plan"])
-    return Model(ArchConfig(**config["arch"], plan=plan))
+    return Model(from_json(ArchConfig,
+                           dict(config["arch"], plan=config["plan"])))
+
+
+def from_json(cls, values: dict):
+    """The dataclass `cls` from its fields as a JSON file spells them: a
+    field typed as a dataclass (or an optional one) from its mapping, a
+    tuple from its list, by the field's annotation.  So a configuration
+    file can carry any section the program's config classes declare (an
+    `ArchConfig`'s `moe`, its `layer_pattern`) with no edit here."""
+    hints = get_type_hints(cls)
+    return cls(**{k: _field_from_json(hints[k], v)
+                  for k, v in values.items()})
+
+
+def _field_from_json(hint, value):
+    kinds = (hint, *get_args(hint))     # Optional[X]: X and None
+    if isinstance(value, dict):
+        for t in kinds:
+            if is_dataclass(t):
+                return from_json(t, value)
+    if isinstance(value, list) and any(get_origin(t) is tuple
+                                       for t in kinds):
+        return tuple(value)
+    return value
 
 
 def seed_key(seed: int):

@@ -33,6 +33,20 @@ import jax.numpy as jnp
 
 from reference.common import mm, rmsnorm, silu
 
+#: `arch` widths at which the CPU tests check this reference against the
+#: program's forward pass (`bench/tests/test_bench_references.py`)
+SMALL_ARCH = dict(n_layers=2, d_model=32, ssm_state=8, ssm_headdim=8,
+                  ssm_chunk=8, vocab_size=96)
+
+
+def published_sizes(f: dict) -> dict:
+    """Each `arch` size that the published `config.json` keys of the
+    configuration file `f` fix, mapped to the value they require: the
+    vocabulary's rows are padded to `pad_vocab_size_multiple`."""
+    pad = f["pad_vocab_size_multiple"]
+    return {"d_model": f["d_model"], "n_layers": f["n_layer"],
+            "vocab_size": -(-f["vocab_size"] // pad) * pad}
+
 
 def _sizes(config: dict):
     a = config["arch"]

@@ -28,6 +28,25 @@ import jax.numpy as jnp
 
 from reference.common import mm, rmsnorm, silu
 
+#: `arch` widths at which the CPU tests check this reference against the
+#: program's forward pass (`bench/tests/test_bench_references.py`)
+SMALL_ARCH = dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_head=16,
+                  d_ff=96, vocab_size=128)
+
+
+def published_sizes(f: dict) -> dict:
+    """Each `arch` size that the published `config.json` keys of the
+    configuration file `f` fix, mapped to the value they require."""
+    hidden, heads = f["hidden_size"], f["num_attention_heads"]
+    if hidden % heads:
+        raise ValueError(f"hidden_size {hidden} is no multiple of "
+                         f"num_attention_heads {heads}")
+    return {"d_model": hidden, "d_ff": f["intermediate_size"],
+            "n_heads": heads, "n_kv_heads": f["num_key_value_heads"],
+            "n_layers": f["num_hidden_layers"],
+            "vocab_size": f["vocab_size"], "rope_theta": f["rope_theta"],
+            "d_head": hidden // heads}
+
 
 def _sizes(config: dict):
     a = config["arch"]

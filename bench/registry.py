@@ -12,6 +12,29 @@ metric therefore adds files and entries and edits none:
     bench/reference/<family>.py      plain float32 forward pass + weights
     bench/counters/<family>.py       model FLOPs per program call
     bench/limits/<cell>.json         limits of the numbers `correct` compares
+
+Adding a configuration of a new family, with its cell:
+
+1. `bench/configs/<config>.json`: the published `config.json` keys, the
+   `reduced` cuts, `reference` and `counter` (family names), `arch` and
+   `plan` as the program's `ArchConfig` and `PlanConfig` spell them (a
+   nested section such as `moe` as a mapping, a tuple as a list;
+   `harness.from_json` turns them back).
+2. `bench/reference/<family>.py`: `make_params(config, key)`,
+   `last_logits(config, params, tokens, n_last, mode)`,
+   `published_sizes(f) -> dict`, which maps each `arch` key to the value
+   the configuration's published keys require (the spec test compares),
+   and `SMALL_ARCH`, the `arch` widths at which the CPU tests check the
+   reference against the program's forward pass.
+3. `bench/counters/<family>.py`: `flops(arch, gen, program, pos)`.
+4. For a new kernel, `bench/kernels/<kernel>.py` (`TRACE_NAMES`, `work`,
+   `call`) and `bench/metrics/<kernel>_roofline.py`; for what the family
+   adds, a metric of its own under `bench/metrics/`, which may read the
+   program's `repro.obs` counters and gauges from `ctx.counters`.
+5. `bench/limits/<cell>.json`, from the readings of `bench/calibrate.py`.
+6. In `BENCHMARK.json`: an entry under `configs`, one under `workloads`,
+   and the new cell's name appended to the `workloads` list of each
+   metric that lists its cells and that the cell reports.
 """
 from __future__ import annotations
 

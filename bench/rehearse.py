@@ -14,7 +14,9 @@ deepest cut that fits.  Nothing runs, so nothing here is a time.
 from __future__ import annotations
 
 import argparse
+import importlib
 import os
+import pkgutil
 import sys
 from pathlib import Path
 
@@ -23,16 +25,22 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(1, str(BENCH.parent / "src"))
 
 
+def kernel_modules() -> list:
+    """`repro.kernels` and each of its modules that binds
+    `resolve_interpret`, a kernel added later among them."""
+    import repro.kernels as K
+    mods = [K] + [importlib.import_module(f"{K.__name__}.{m.name}")
+                  for m in pkgutil.iter_modules(K.__path__)]
+    return [m for m in mods if hasattr(m, "resolve_interpret")]
+
+
 def compile_for_chip():
     """Make the program's kernels compile for the chip although the
     process runs on the CPU (they choose interpret mode from the
     platform when traced)."""
-    import repro.kernels as K
-    from repro.kernels import flash_attention, mriq, ops, rglru, ssd, swiglu
-
     def resolve(interpret):
         return False if interpret is None else interpret
-    for mod in (K, ops, flash_attention, mriq, rglru, ssd, swiglu):
+    for mod in kernel_modules():
         mod.resolve_interpret = resolve
 
 

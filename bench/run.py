@@ -128,8 +128,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devs, peaks: dict,
     del steps
     t_end = time.perf_counter()
 
-    fallbacks = {k: v["value"] for k, v in obs.METRICS.to_json().items()
-                 if k.startswith("kernel_fallback_")}
+    counters = {k: v["value"] for k, v in obs.METRICS.to_json().items()
+                if "value" in v}
+    kernel_counters = {k: v for k, v in counters.items()
+                       if k.startswith("kernel_")}
     ws = harness.wave_stats(win, gen.batch)
     log(f"window: {len(win.waves)} waves, {ws['requests_started']} requests "
         f"started, {ws['requests_finished']} finished, "
@@ -139,8 +141,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devs, peaks: dict,
     log(f"gc inside the window: {len(pauses.pauses)} collections, "
         f"{sum(1 for g, _ in pauses.pauses if g == 2)} full, longest "
         f"{1e3 * max((s for _, s in pauses.pauses), default=0):.3f} ms")
-    log(f"compiles inside the window: {in_window}; kernel fallbacks: "
-        f"{fallbacks or 'none'}; peak_bytes_in_use={peak} "
+    log(f"compiles inside the window: {in_window}; kernel counters: "
+        f"{kernel_counters or 'none'}; peak_bytes_in_use={peak} "
         f"bytes_limit={stats.get('bytes_limit')}")
 
     # -- correctness: after the window, with the program's state freed ------
@@ -197,7 +199,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devs, peaks: dict,
         tr = from_xplane(find_xplane(trace_dir), harness.SPANS)
         lo, hi = tr.span("slice")
         ctx = MetricContext(trace=tr, lo=lo, hi=hi, cell=cell, gen=gen,
-                            calls=tracer.calls, peaks=peaks)
+                            calls=tracer.calls, peaks=peaks,
+                            counters=counters)
         for m in cell.per_layer:
             v = cell.metric(m["name"]).read(ctx)
             if v is not None:
@@ -225,11 +228,15 @@ def _wave_calls(wave, gen, end: float):
 
 
 class MetricContext:
-    """What a per-layer metric's `read(ctx)` may use."""
+    """What a per-layer metric's `read(ctx)` may use.  `counters` holds the
+    value of each counter and gauge on `repro.obs.METRICS`, read after
+    the window: what the program counted while it traced and ran."""
 
-    def __init__(self, trace, lo, hi, cell, gen, calls, peaks):
+    def __init__(self, trace, lo, hi, cell, gen, calls, peaks,
+                 counters=None):
         self.trace, self.lo, self.hi = trace, lo, hi
         self.cell, self.gen, self.calls, self.peaks = cell, gen, calls, peaks
+        self.counters = counters or {}
 
 
 def main(argv=None) -> int:

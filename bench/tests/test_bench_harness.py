@@ -34,6 +34,18 @@ def read(ctx):
     return float(sum(1 for prog, _ in ctx.calls if prog == "decode"))
 '''
 
+COUNTER_METRIC = '''"""Traces whose swiglu read the stacked weights, a `repro.obs`
+counter (a test's new metric)."""
+NAME = "stacked_swiglu_traces"
+UNIT = "traces"
+LAYER = "kernels"
+MOVES = "tpot_p95_ms"
+
+
+def read(ctx):
+    return ctx.counters.get("kernel_stacked_swiglu")
+'''
+
 
 @pytest.fixture(scope="module")
 def tiny_root(tmp_path_factory):
@@ -54,6 +66,7 @@ def tiny_root(tmp_path_factory):
         f"limits/{CELL}.json": json.dumps(
             {"max_logit_gap": {"limit": 0.2, "lower": 0.02, "upper": 2.6}}),
         "metrics/decode_calls.py": NEW_METRIC,
+        "metrics/stacked_swiglu_traces.py": COUNTER_METRIC,
     }
     for rel, text in new.items():
         (root / "bench" / rel).write_text(text)
@@ -68,6 +81,10 @@ def tiny_root(tmp_path_factory):
     spec["per_layer"].append({"name": "decode_calls", "unit": "calls",
                               "better": "higher", "source": "device_trace",
                               "layer": "serving step programs",
+                              "moves": "tpot_p95_ms", "workloads": [CELL]})
+    spec["per_layer"].append({"name": "stacked_swiglu_traces",
+                              "unit": "traces", "better": "higher",
+                              "source": "program_counter", "layer": "kernels",
                               "moves": "tpot_p95_ms", "workloads": [CELL]})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     after = {p.relative_to(root): p.read_bytes()
@@ -98,6 +115,9 @@ def test_new_metric_is_read_in_a_traced_run(tiny_root, tmp_path):
     assert result["correct"] is True
     # the tiny mix's first wave: 7 decode steps, all in the traced slice
     assert result["metrics"]["decode_calls"]["value"] == 7.0
+    # read from `repro.obs`: the prefill's trace; the decode step's 2 rows
+    # are too few for the kernel and take its oracle
+    assert result["metrics"]["stacked_swiglu_traces"]["value"] == 1
     assert result["device"]["window_s"] > 0
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
 

@@ -1,27 +1,37 @@
 """The plain references against the program's forward pass, on the CPU at
-reduced widths: a wrong yardstick fails here, not on the chip."""
+reduced widths: a wrong yardstick fails here, not on the chip.  Each
+family's reference gives its widths as `SMALL_ARCH`; every configuration
+of `BENCHMARK.json` is checked, through its first cell."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import harness
-from registry import load_cell
+from registry import ROOT, load_cell, load_json
 from repro.models import transformer as T
 
-SMALL = {
-    "qwen2-7b.prompt_heavy": dict(n_layers=2, d_model=64, n_heads=4,
-                                  n_kv_heads=2, d_head=16, d_ff=96,
-                                  vocab_size=128),
-    "mamba2-1.3b.prompt_heavy": dict(n_layers=2, d_model=32, ssm_state=8,
-                                     ssm_headdim=8, ssm_chunk=8,
-                                     vocab_size=96),
-}
+
+def one_cell_a_config() -> list:
+    first = {}
+    for w in load_json(ROOT / "BENCHMARK.json")["workloads"]:
+        first.setdefault(w["config"], w["name"])
+    return sorted(first.values())
+
+
+def small_arch(cell_name: str) -> dict:
+    cell = load_cell(cell_name)
+    ref = cell.reference()
+    assert hasattr(ref, "SMALL_ARCH"), (
+        f"bench/reference/{cell.config['reference']}.py has no SMALL_ARCH: "
+        "add a dict of small arch widths at which the CPU tests check it "
+        "against the program's forward pass")
+    return ref.SMALL_ARCH
 
 
 def small_f32(cell_name: str, **departures) -> dict:
     config = dict(load_cell(cell_name).config)
-    config["arch"] = dict(config["arch"], **SMALL[cell_name])
+    config["arch"] = dict(config["arch"], **small_arch(cell_name))
     config["plan"] = dict(config["plan"], compute_dtype="float32",
                           param_dtype="float32", kv_cache_dtype="float32",
                           attn_impl="xla", mlp_impl="xla", ssm_impl="xla")
@@ -44,7 +54,7 @@ def logits_both(cell_name: str, config: dict, seq: int = 24):
     return np.asarray(prog, np.float32), np.asarray(want)
 
 
-@pytest.mark.parametrize("cell_name", sorted(SMALL))
+@pytest.mark.parametrize("cell_name", one_cell_a_config())
 def test_reference_matches_program_forward(cell_name):
     config = small_f32(cell_name)
     got, want = logits_both(cell_name, config)

@@ -27,7 +27,9 @@ def test_names_and_keys():
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_loads_with_every_file_it_names(cell):
     c = load_cell(cell)
-    assert c.chips == 1
+    assert c.chips in (1, 4)
+    four = [w["name"] for w in SPEC["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(CELLS) // 2), four
     assert c.config["arch"]["n_layers"] >= 1
     assert c.reference().last_logits and c.counter().flops
     lim = c.limits()["max_logit_gap"]
@@ -59,25 +61,41 @@ def test_config_file_states_its_cut(config):
         assert f[key] != cut["published"]
 
 
-#: published key -> the program's `ArchConfig` key, per reference family
-ARCH_KEYS = {
-    "qwen2": {"hidden_size": "d_model", "intermediate_size": "d_ff",
-              "num_attention_heads": "n_heads",
-              "num_key_value_heads": "n_kv_heads",
-              "num_hidden_layers": "n_layers", "vocab_size": "vocab_size",
-              "rope_theta": "rope_theta"},
-    "mamba2": {"d_model": "d_model", "n_layer": "n_layers"},
-}
+def sizes_off(f: dict) -> dict:
+    """The `arch` sizes of configuration file `f` that differ from what its
+    published keys require, by the reference family's `published_sizes`:
+    {key: (in the file, required)}."""
+    family = f["reference"]
+    ref = load_module(BENCH_DIR / "reference" / f"{family}.py")
+    assert hasattr(ref, "published_sizes"), (
+        f"bench/reference/{family}.py has no published_sizes(f: dict) -> "
+        "dict: add one that maps each arch key to the value the "
+        "configuration's published keys require")
+    arch = f["arch"]
+    return {k: (arch.get(k), v) for k, v in ref.published_sizes(f).items()
+            if arch.get(k) != v}
 
 
 @pytest.mark.parametrize("config", SPEC["configs"], ids=lambda c: c["name"])
 def test_program_sizes_are_the_published_ones(config):
-    f = load_json(ROOT / config["file"])
-    arch = f["arch"]
-    for pub, key in ARCH_KEYS[f["reference"]].items():
-        assert arch[key] == f[pub], pub
-    if f["reference"] == "qwen2":
-        assert arch["d_head"] * arch["n_heads"] == f["hidden_size"]
-    if f["reference"] == "mamba2":
-        pad = f["pad_vocab_size_multiple"]
-        assert arch["vocab_size"] == -(-f["vocab_size"] // pad) * pad
+    assert sizes_off(load_json(ROOT / config["file"])) == {}
+
+
+@pytest.mark.parametrize("config, arch, off", [
+    ("qwen2-7b", {"d_head": 100}, "d_head"),     # 100 does not divide 3584
+    ("qwen2-7b", {"n_heads": 32, "d_head": 112}, "n_heads"),
+    ("qwen2-7b", {"rope_theta": 10000.0}, "rope_theta"),
+    ("mamba2-1.3b", {"vocab_size": 50277}, "vocab_size"),   # unpadded
+    ("mamba2-1.3b", {"n_layers": 24}, "n_layers"),
+])
+def test_a_wrong_program_size_is_caught(config, arch, off):
+    f = load_json(BENCH_DIR / "configs" / f"{config}.json")
+    f["arch"] = dict(f["arch"], **arch)
+    assert off in sizes_off(f)
+
+
+def test_heads_that_do_not_divide_the_width_are_caught():
+    f = load_json(BENCH_DIR / "configs" / "qwen2-7b.json")
+    f["num_attention_heads"] = f["arch"]["n_heads"] = 27
+    with pytest.raises(ValueError, match="no multiple"):
+        sizes_off(f)

@@ -6,7 +6,7 @@ import pytest
 
 import run
 import traffic
-from registry import load_cell, load_peaks
+from registry import load_cell, load_module, load_peaks
 from xtrace import Event, Trace, program_name
 
 MS = 1e6        # the trace's clock counts nanoseconds
@@ -36,6 +36,24 @@ def ctx_for(trace, cell_name="qwen2-7b.prompt_heavy", calls=()):
     return run.MetricContext(trace=trace, lo=lo, hi=hi, cell=cell, gen=gen,
                              calls=list(calls),
                              peaks=load_peaks("TPU v5 lite"))
+
+
+COUNTER_METRIC = '''"""Traces whose swiglu read the stacked weights (a test's)."""
+
+
+def read(ctx):
+    return ctx.counters.get("kernel_stacked_swiglu")
+'''
+
+
+def test_a_metric_reads_a_program_counter(tmp_path):
+    (tmp_path / "stacked_swiglu_traces.py").write_text(COUNTER_METRIC)
+    metric = load_module(tmp_path / "stacked_swiglu_traces.py")
+    c = ctx_for(hand_trace())
+    assert c.counters == {}
+    assert metric.read(c) is None           # nothing to read: no number
+    c.counters = {"kernel_stacked_swiglu": 2.0, "kernel_fallback_ssd": 1.0}
+    assert metric.read(c) == 2.0
 
 
 def test_busy_union_and_idle_share():
