@@ -26,5 +26,6 @@ from repro.configs import (  # noqa: F401
     moonshot_v1_16b_a3b,
     granite_moe_1b_a400m,
     mamba2_1p3b,
+    granite_4_0_h_micro,
     tiny,
 )

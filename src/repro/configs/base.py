@@ -127,6 +127,14 @@ class ArchConfig:
     act: str = "swiglu"         # swiglu | gelu
     norm: str = "rmsnorm"       # rmsnorm | layernorm
     rope_theta: float = 10_000.0
+    rope: bool = True           # rotary positions on q and k; False: none
+    norm_eps: float = 1e-6      # every norm's eps, the SSM's gated one too
+
+    # muP multipliers (Granite); each default leaves its op out
+    embed_scale: float = 1.0    # times the embedding's output
+    residual_scale: float = 1.0     # times each sublayer's output
+    attn_scale: float = 0.0     # softmax scale; 0 means 1/sqrt(d_head)
+    logit_scale: float = 1.0    # times the logits
 
     # MoE
     moe: Optional[MoEConfig] = None
@@ -137,8 +145,11 @@ class ArchConfig:
     ssm_headdim: int = 64
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    ssm_act_bc: bool = False    # silu on all of xBC after the conv, as
+                                # published; False: on x alone
 
-    # hybrid (recurrentgemma): pattern unit, e.g. ("rec", "rec", "attn")
+    # hybrid: pattern unit, e.g. ("rec", "rec", "attn") for RecurrentGemma;
+    # an "ssm" layer of a hybrid with d_ff > 0 carries an MLP too
     layer_pattern: tuple[str, ...] = ()
     local_window: int = 0       # sliding-window size for local attention
     lru_width: int = 0          # RG-LRU recurrence width (defaults to d_model)
@@ -210,9 +221,9 @@ class ArchConfig:
         per_kind["ssm"] = (
             d * (2 * di + 2 * ns + nh)  # in_proj(z,x,B,C,dt)
             + di * d                    # out_proj
-            + (di + 2 * ns) * self.ssm_conv
-            + 2 * nh + di               # A, D, norm
-            + 2 * d
+            + (di + 2 * ns) * (self.ssm_conv + 1)   # conv weights, bias
+            + 3 * nh + di               # A, D, dt_bias, gated norm
+            + d                         # norm1
         )
         w = self.lru_width or d
         per_kind["rec"] = (
@@ -225,6 +236,8 @@ class ArchConfig:
             # hybrid attention layers also carry an MLP; rec layers too
             per_kind["attn"] = attn + mlp + 2 * d
             per_kind["rec"] += mlp
+            if self.d_ff:
+                per_kind["ssm"] += mlp + d      # norm2 and the MLP
         for kind in self.layer_kinds():
             n += per_kind[kind]
         n += d  # final norm

@@ -55,7 +55,9 @@ def site_census(cfg: ArchConfig, shape: ShapeSpec,
     n_attn = sum(1 for k in kinds if k == "attn")
     n_ssm = sum(1 for k in kinds if k == "ssm")
     n_rec = sum(1 for k in kinds if k == "rec")
-    n_mlp = n_attn + n_rec if cfg.family in ("hybrid",) else n_attn
+    n_mlp = n_attn
+    if cfg.family == "hybrid":      # its recurrent layers carry MLPs too
+        n_mlp += n_rec + (n_ssm if cfg.d_ff else 0)
 
     if shape.kind == "decode":
         t = shape.global_batch          # one token per sequence

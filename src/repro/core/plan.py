@@ -24,9 +24,10 @@ GENES: dict[str, tuple[tuple, Any]] = {
                   lambda cfg, kind: cfg.n_heads > 0),
     "mlp_impl": (("xla", "pallas"),
                  lambda cfg, kind: cfg.d_ff > 0 or cfg.moe is not None),
-    "ssm_impl": (("xla", "pallas"), lambda cfg, kind: cfg.family == "ssm"),
+    "ssm_impl": (("xla", "pallas"),
+                 lambda cfg, kind: "ssm" in cfg.layer_kinds()),
     "rglru_impl": (("xla", "pallas"),
-                   lambda cfg, kind: cfg.family == "hybrid"),
+                   lambda cfg, kind: "rec" in cfg.layer_kinds()),
     "fsdp": ((False, True), lambda cfg, kind: True),
     "seq_shard": ((False, True), lambda cfg, kind: True),
     "use_tp": ((False, True), lambda cfg, kind: True),

@@ -188,7 +188,12 @@ def _layer_panels(wi, wg, wo, layer):
 
 @jax.custom_vjp
 def _swiglu_op(xf, wi, wg, wo, layer):
-    bt, bf = swiglu_blocks(xf.shape[0], wi.shape[-1], _compiled())
+    t = xf.shape[0]
+    compiled = _compiled()
+    # the compiled kernel takes fewer rows than a sublane tile (a decode
+    # step of a small batch) padded to one
+    rows = max(t, SUBLANE) if compiled else t
+    bt, bf = swiglu_blocks(rows, wi.shape[-1], compiled)
     if bt < 8 or bf < 8:
         _fallback("swiglu")
         return _ref.swiglu_ref(xf, *_layer_panels(wi, wg, wo, layer))
@@ -197,7 +202,10 @@ def _swiglu_op(xf, wi, wg, wo, layer):
             "kernel_stacked_swiglu",
             "traces whose swiglu read its panels from the stacked weights "
             "by layer index").inc()
-    return _swiglu(xf, wi, wg, wo, layer, block_t=bt, block_f=bf)
+    if rows == t:
+        return _swiglu(xf, wi, wg, wo, layer, block_t=bt, block_f=bf)
+    xf = jnp.pad(xf, ((0, rows - t), (0, 0)))
+    return _swiglu(xf, wi, wg, wo, layer, block_t=bt, block_f=bf)[:t]
 
 
 def _swiglu_fwd(xf, wi, wg, wo, layer):

@@ -50,11 +50,11 @@ def apply_norm(params, x, cfg: ArchConfig):
     if cfg.norm == "layernorm":
         mu = jnp.mean(x32, axis=-1, keepdims=True)
         var = jnp.var(x32, axis=-1, keepdims=True)
-        y = (x32 - mu) * lax.rsqrt(var + 1e-6)
+        y = (x32 - mu) * lax.rsqrt(var + cfg.norm_eps)
         y = y * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)
     else:
         ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        y = x32 * lax.rsqrt(ms + 1e-6) * params["scale"].astype(jnp.float32)
+        y = x32 * lax.rsqrt(ms + cfg.norm_eps) * params["scale"].astype(jnp.float32)
     return y.astype(dt)
 
 
@@ -114,8 +114,13 @@ def _qkv(params, x, cfg: ArchConfig, positions):
         q = q + params["bq"].astype(dt)
         k = k + params["bk"].astype(dt)
         v = v + params["bv"].astype(dt)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    if cfg.attn_scale:
+        # every attention path scales the scores by 1/sqrt(d_head): the
+        # rest of the configured scale goes on q, once
+        q = q * (cfg.attn_scale * math.sqrt(q.shape[-1]))
     return q, k, v
 
 
@@ -207,9 +212,10 @@ def run_attention(params, x, cfg: ArchConfig, plan: PlanConfig, positions,
     The KV cache is a rolling buffer of length T (= min(window, seq) for
     local attention, full seq otherwise) with an explicit per-slot position
     array ``kpos`` (-1 = empty); decode writes slot ``pos % T``.  Keys are
-    stored post-RoPE.  ``kv_cache_dtype='int8'`` stores per-(pos, head)
-    absmax-quantized values + f32 scales (halves cache bytes AND the
-    cross-TP cache all-gather payload — a §Perf lever).
+    stored post-RoPE (where ``cfg.rope``).  ``kv_cache_dtype='int8'``
+    stores per-(pos, head) absmax-quantized values + f32 scales (halves
+    cache bytes AND the cross-TP cache all-gather payload — a §Perf
+    lever).
     """
     q, k, v = _qkv(params, x, cfg, positions)
     causal = not cfg.is_encoder

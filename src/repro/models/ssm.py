@@ -63,6 +63,17 @@ def _split_proj(zxbcdt, cfg: ArchConfig):
     return z, xbc, dt
 
 
+def _act_x(xbc, cfg: ArchConfig):
+    """(x after its activation, xBC to take B and C from): with
+    ``cfg.ssm_act_bc`` silu on all of xBC, as the published block has it,
+    else on x alone."""
+    di = cfg.d_inner
+    if cfg.ssm_act_bc:
+        xbc = jax.nn.silu(xbc)
+        return xbc[..., :di], xbc
+    return jax.nn.silu(xbc[..., :di]), xbc
+
+
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int):
     """Chunked SSD scan.
 
@@ -128,7 +139,8 @@ def run_mamba2(params, x, cfg: ArchConfig, plan: PlanConfig,
     if decode:
         xbc, new_conv = _causal_conv(xbc, params["conv_w"].astype(dt_c),
                                      params["conv_b"].astype(dt_c), conv_state)
-        xin = jax.nn.silu(xbc[..., :di]).reshape(x.shape[0], 1, h, p)
+        xin, xbc = _act_x(xbc, cfg)
+        xin = xin.reshape(x.shape[0], 1, h, p)
         Bm = xbc[..., di:di + n]
         Cm = xbc[..., di + n:]
         hs = cache["ssm"]                                   # (B,H,P,N)
@@ -144,7 +156,7 @@ def run_mamba2(params, x, cfg: ArchConfig, plan: PlanConfig,
     else:
         xbc, new_conv = _causal_conv(xbc, params["conv_w"].astype(dt_c),
                                      params["conv_b"].astype(dt_c), None)
-        xin = jax.nn.silu(xbc[..., :di])
+        xin, xbc = _act_x(xbc, cfg)
         Bm = xbc[..., di:di + n]
         Cm = xbc[..., di + n:]
         xh = xin.reshape(x.shape[0], x.shape[1], h, p)
@@ -161,7 +173,8 @@ def run_mamba2(params, x, cfg: ArchConfig, plan: PlanConfig,
     y = y.reshape(x.shape[0], -1, di)
     # gated RMSNorm (mamba2)
     y32 = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    y32 = y32 * lax.rsqrt(jnp.mean(jnp.square(y32), -1, keepdims=True) + 1e-6)
+    y32 = y32 * lax.rsqrt(jnp.mean(jnp.square(y32), -1, keepdims=True)
+                          + cfg.norm_eps)
     y = (y32 * params["norm"].astype(jnp.float32)).astype(dt_c)
     out = jnp.einsum("bsw,wd->bsd", y, params["out_proj"].astype(dt_c))
     return out, new_cache

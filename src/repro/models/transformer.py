@@ -42,7 +42,11 @@ def unit_structure(cfg: ArchConfig) -> tuple[tuple[str, ...], int, tuple[str, ..
 def init_layer(key, cfg: ArchConfig, kind: str):
     ks = jax.random.split(key, 3)
     if kind == "ssm":
-        return {"norm1": L.init_norm(cfg), "mixer": S.init_mamba2(ks[0], cfg)}
+        p = {"norm1": L.init_norm(cfg), "mixer": S.init_mamba2(ks[0], cfg)}
+        if cfg.d_ff:        # a hybrid's SSM layer with an MLP of its own
+            p["norm2"] = L.init_norm(cfg)
+            p["mlp"] = L.init_mlp(ks[1], cfg)
+        return p
     p = {"norm1": L.init_norm(cfg), "norm2": L.init_norm(cfg)}
     if kind == "attn":
         p["mixer"] = L.init_attention(ks[0], cfg)
@@ -79,24 +83,28 @@ def apply_layer(params, x, kind: str, cfg: ArchConfig, plan: PlanConfig,
         with jax.named_scope("ssm"):
             mix, new_cache = S.run_mamba2(params["mixer"], h, cfg, plan,
                                           cache, decode)
-        x = x + mix
-        if rules is not None:
-            x = constrain(x, rules, "batch", "seq_sharded", "act_embed")
-        return x, new_cache, aux
     else:
         raise ValueError(kind)
-    x = x + mix
-    h = L.apply_norm(params["norm2"], x, cfg)
-    if "moe" in params:
-        with jax.named_scope("moe"):
-            ff, aux = L.run_moe(params["moe"], h, cfg, plan)
-    else:
-        with jax.named_scope("mlp"):
-            ff = L.run_mlp(params["mlp"], h, cfg, plan, mlp_layer)
-    x = x + ff
+    x = _residual(x, mix, cfg)
+    if "norm2" in params:       # all but an SSM layer with no MLP
+        h = L.apply_norm(params["norm2"], x, cfg)
+        if "moe" in params:
+            with jax.named_scope("moe"):
+                ff, aux = L.run_moe(params["moe"], h, cfg, plan)
+        else:
+            with jax.named_scope("mlp"):
+                ff = L.run_mlp(params["mlp"], h, cfg, plan, mlp_layer)
+        x = _residual(x, ff, cfg)
     if rules is not None:
         x = constrain(x, rules, "batch", "seq_sharded", "act_embed")
     return x, new_cache, aux
+
+
+def _residual(x, y, cfg: ArchConfig):
+    """x plus a sublayer's output y, scaled by ``cfg.residual_scale``."""
+    if cfg.residual_scale != 1.0:
+        y = y * cfg.residual_scale
+    return x + y
 
 
 def init_layer_cache(cfg: ArchConfig, kind: str, batch: int, seq_len: int,
@@ -246,6 +254,8 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: PlanConfig,
     unit, n_full, tail = unit_structure(cfg)
     with jax.named_scope("embed"):
         h = embed_inputs(params, batch, cfg, plan, rules)
+        if cfg.embed_scale != 1.0:
+            h = h * cfg.embed_scale
     b, s = h.shape[0], h.shape[1]
 
     if decode:
@@ -320,6 +330,11 @@ def forward(params, batch: dict, cfg: ArchConfig, plan: PlanConfig,
 
     with jax.named_scope("head"):
         h = L.apply_norm(params["final_norm"], h, cfg)
+        if cfg.logit_scale != 1.0:
+            # on the matmul's input: a prefill's slice of its last
+            # position still reaches the matmul, and a power of two
+            # scales the logits exactly
+            h = h * cfg.logit_scale
         wout = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"])
         logits = jnp.einsum("bsd,dv->bsv", h, wout.astype(h.dtype))

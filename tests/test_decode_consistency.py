@@ -12,7 +12,8 @@ from repro.models import transformer as T
 
 DECODABLE = ["qwen2-7b", "granite-20b", "llama3-405b", "stablelm-12b",
              "internvl2-76b", "recurrentgemma-9b", "mamba2-1.3b",
-             "moonshot-v1-16b-a3b", "granite-moe-1b-a400m"]
+             "moonshot-v1-16b-a3b", "granite-moe-1b-a400m",
+             "granite-4.0-h-micro"]
 
 
 def _f32(cfg):

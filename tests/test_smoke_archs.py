@@ -76,6 +76,7 @@ def test_full_config_registered(arch):
         "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
         "granite-moe-1b-a400m": (24, 1024, 16, 8, 512, 49155),
         "mamba2-1.3b": (48, 2048, 0, 0, 0, 50280),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }
     L, d, h, kv, ff, v = table[arch]
     assert cfg.n_layers == L and cfg.d_model == d

@@ -52,6 +52,25 @@ def test_stacked_op_falls_back_on_the_layer_it_names(layer):
     np.testing.assert_array_equal(y, y0)
 
 
+@pytest.mark.parametrize("t", [1, 2, 5])
+def test_compiled_op_pads_fewer_rows_than_a_sublane(t, monkeypatch):
+    # with the chip's block rules, t < 8 rows run the kernel padded to 8
+    # rows and come back cut to t, on the stacked layer; no oracle
+    monkeypatch.setattr(ops, "_compiled", lambda: True)
+    x, wi, wg, wo = _stacks(3, t, 16, 128)
+    reg = obs.set_metrics(obs.MetricsRegistry())
+    try:
+        y = ops.fused_swiglu(x, wi, wg, wo, jnp.int32(1))
+        counts = {k: v.get("value") for k, v in reg.to_json().items()}
+    finally:
+        obs.set_metrics(None)
+    assert counts == {"kernel_stacked_swiglu": 1.0}
+    assert y.shape == (t, 16)
+    # float32 on both sides: they differ only by the order of the sums
+    np.testing.assert_allclose(y, ref.swiglu_ref(x, wi[1], wg[1], wo[1]),
+                               rtol=1e-5, atol=1e-5)
+
+
 def _config(name, **plan):
     """Tiny float32 configs on the Pallas MLP: dense (its d_ff split into
     two kernel blocks) and a hybrid unit of two recurrent layers and one

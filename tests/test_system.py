@@ -95,9 +95,13 @@ def test_plan_genome_covers_all_assigned_families():
         cfg = get_config(arch)
         genes = PlanGenome.gene_names(cfg, "train")
         assert genes, arch
+        kinds = set(cfg.layer_kinds())
         if cfg.family == "ssm":
             assert "ssm_impl" in genes and "attn_impl" not in genes
         if cfg.family == "hybrid":
-            assert "rglru_impl" in genes and "attn_impl" in genes
+            assert "attn_impl" in genes
+        # a recurrent site is a gene wherever a layer of its kind runs
+        assert ("rglru_impl" in genes) == ("rec" in kinds), arch
+        assert ("ssm_impl" in genes) == ("ssm" in kinds), arch
         if cfg.moe is not None:
             assert "mlp_impl" in genes

@@ -114,6 +114,10 @@ def _tiny(name: str):
                           d_model=256, n_heads=2, n_kv_heads=1, d_head=128,
                           d_ff=512, vocab_size=1024, qkv_bias=True,
                           plan=plan)
+    if name == "granite":   # Mamba-2 and attention, an MLP in each
+        return dataclasses.replace(
+            get_config("granite-4.0-h-micro", reduced=True), d_model=256,
+            n_heads=2, n_kv_heads=1, d_head=128, d_ff=512, plan=plan)
     arch = {"ssm": "mamba2-1.3b", "moe": "granite-moe-1b-a400m",
             "hybrid": "recurrentgemma-9b"}[name]
     return dataclasses.replace(get_config(arch, reduced=True), plan=plan)
@@ -158,6 +162,8 @@ def _scopes(op_name: str) -> list:
              "head"}),
     ("hybrid", {"embed", "layers", "layer", "attn", "kv_write", "rec",
                 "mlp", "head"}),
+    ("granite", {"embed", "layers", "layer", "attn", "kv_write", "ssm",
+                 "mlp", "head"}),
 ])
 def test_decode_step_names_its_scopes(name, want, one_chip,
                                       compiled_kernels):
@@ -190,3 +196,26 @@ def test_decode_swiglu_reads_the_stacked_weights(one_chip,
     assert all(w.startswith("get-tuple-element") for w in weights), operands
     assert not any(o.startswith(("dynamic-slice", "copy"))
                    for o in operands), operands
+
+
+def test_swiglu_takes_fewer_rows_than_a_sublane(one_chip, compiled_kernels):
+    """A decode step of 2 rows (granite-4.0-h-micro's long_prompt batch)
+    at published widths runs the compiled kernel on the stacked weights,
+    its rows padded to a sublane, and takes no oracle: the oracle would
+    slice, so copy, the layer's panels out of the stacks."""
+    from repro import obs
+    n, d, f = 4, 2048, 8192
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    reg = obs.set_metrics(obs.MetricsRegistry())
+    try:
+        text = jax.jit(ops.fused_swiglu).lower(
+            arg(2, 1, d), arg(n, d, f), arg(n, d, f), arg(n, f, d),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        ).compile().as_text()
+        counts = {k: v.get("value") for k, v in reg.to_json().items()}
+    finally:
+        obs.set_metrics(None)
+    assert "tpu_custom_call" in text
+    assert counts == {"kernel_stacked_swiglu": 1.0}
