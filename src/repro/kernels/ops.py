@@ -27,6 +27,7 @@ from repro import obs
 from repro.kernels import ref as _ref
 from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention import flash_attention as _flash
+from repro.kernels.flash_attention import skipped_share
 from repro.kernels.mriq import mriq_pallas as _mriq
 from repro.kernels.rglru import rglru_pallas as _rglru
 from repro.kernels.ssd import ssd_pallas as _ssd
@@ -62,9 +63,13 @@ def _fallback(kernel: str) -> None:
 
 
 def flash_blocks(s: int, t: int, compiled: bool) -> tuple[int, int]:
-    """(block_q, block_k) for S queries over T keys."""
+    """(block_q, block_k) for S queries over T keys: up to 512 queries
+    over 1024 keys, so that each grid step's fixed cost is spread over
+    half a million scores; the largest that fit the default scoped VMEM
+    at d_head 256, and on a v5e the fastest such at 2048 and 16384
+    positions."""
     a = _align(SUBLANE, compiled)
-    return _blk(s, 128, a), _blk(t, 128, a)
+    return _blk(s, 512, a), _blk(t, 1024, a)
 
 
 def mriq_blocks(n: int, m: int, compiled: bool) -> tuple[int, int]:
@@ -96,6 +101,12 @@ def _flash_op(q, k, v, causal, window):
     if bq < 8 or bk < 8:
         _fallback("flash_attention")
         return _ref.flash_attention_ref(q, k, v, causal, window)
+    obs.METRICS.gauge(
+        "kernel_flash_skipped_tiles",
+        "share of the flash kernel's grid steps, in the last call traced, "
+        "that skip a tile the mask removes whole").set(skipped_share(
+            q.shape[1], k.shape[1], causal=causal, window=window,
+            block_q=bq, block_k=bk))
     return _flash(q, k, v, causal=causal, window=window,
                   block_q=bq, block_k=bk)
 

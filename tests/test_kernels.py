@@ -7,8 +7,9 @@ import pytest
 pytest.importorskip("hypothesis")  # property tests need the dev dep
 from hypothesis import given, settings, strategies as st
 
+from repro import obs
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention, kv_block
 from repro.kernels.mriq import mriq_pallas
 from repro.kernels.rglru import rglru_pallas
 from repro.kernels.ssd import ssd_pallas
@@ -57,6 +58,107 @@ def test_flash_attention_sweep(dtype, hq, hkv, causal, window):
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o0, np.float32), atol=tol,
                                rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("bq,bk", [(32, 32), (32, 64), (64, 32)])
+@pytest.mark.parametrize("causal,window", [(True, 0), (True, 48),
+                                           (False, 0), (False, 48)])
+def test_flash_attention_sweep_many_blocks(dtype, bq, bk, causal, window):
+    """Eight blocks or more a side: the causal mask removes whole tiles
+    above the diagonal and the window whole tiles left of its edge, and
+    the kernel skips both."""
+    k = _keys(3)
+    b, s, hq, hkv, d = 1, 256, 4, 2, 16
+    q = jax.random.normal(k[0], (b, s, hq, d), dtype)
+    kk = jax.random.normal(k[1], (b, s, hkv, d), dtype)
+    v = jax.random.normal(k[2], (b, s, hkv, d), dtype)
+    o = flash_attention(q, kk, v, causal=causal, window=window,
+                        block_q=bq, block_k=bk)
+    o0 = ref.flash_attention_ref(q, kk, v, causal, window)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(o0, np.float32), atol=tol,
+                               rtol=tol)
+
+
+@pytest.mark.parametrize("bq,bk", [(32, 32), (16, 64), (64, 16)])
+def test_flash_attention_reads_no_skipped_kv_block(bq, bk):
+    """A NaN in the last key's value reaches only the q blocks whose
+    live kv blocks include it: the others compute nothing on its block."""
+    k = _keys(3)
+    b, s, hq, hkv, d = 1, 128, 2, 1, 8
+    q = jax.random.normal(k[0], (b, s, hq, d))
+    kk = jax.random.normal(k[1], (b, s, hkv, d))
+    v = jax.random.normal(k[2], (b, s, hkv, d))
+    o = flash_attention(q, kk, v.at[:, -1].set(jnp.nan), block_q=bq,
+                        block_k=bk)
+    # rows whose q block ends before the last kv block begins
+    clean = (s - bk) // bq * bq
+    assert clean > 0
+    np.testing.assert_allclose(o[:, :clean],
+                               ref.flash_attention_ref(q, kk, v)[:, :clean],
+                               atol=2e-5, rtol=2e-5)
+    assert np.isnan(np.asarray(o[:, clean:])).all()
+
+
+def _masked_tile_share(s, bq, bk, causal, window):
+    """Share of (bq, bk) tiles of the S x S mask with no position kept."""
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    keep = np.ones((s, s), bool)
+    if causal:
+        keep &= j <= i
+    if window:
+        keep &= i - j < window
+    tiles = keep.reshape(s // bq, bq, s // bk, bk).any(axis=(1, 3))
+    return 1 - tiles.mean()
+
+
+TILINGS = [
+    (128, 16, 16, True, 0), (128, 32, 16, True, 0), (128, 16, 64, True, 0),
+    (128, 16, 16, True, 40), (128, 32, 64, True, 24),
+    (128, 16, 32, False, 40), (128, 32, 32, False, 0),
+    (64, 64, 64, True, 0)]
+
+
+@pytest.mark.parametrize("s,bq,bk,causal,window", TILINGS)
+def test_flash_skipped_tiles_gauge_counts_masked_tiles(
+        s, bq, bk, causal, window, monkeypatch):
+    monkeypatch.setattr(ops, "flash_blocks", lambda *_: (bq, bk))
+    k = _keys(3)
+    q = jax.random.normal(k[0], (1, s, 2, 8))
+    kk = jax.random.normal(k[1], (1, s, 1, 8))
+    v = jax.random.normal(k[2], (1, s, 1, 8))
+    reg = obs.set_metrics(obs.MetricsRegistry())
+    try:
+        o = ops.flash_attention(q, kk, v, causal=causal, window=window)
+        share = reg.gauge("kernel_flash_skipped_tiles").value
+    finally:
+        obs.set_metrics(None)
+    assert share == pytest.approx(
+        _masked_tile_share(s, bq, bk, causal, window), abs=1e-12)
+    np.testing.assert_allclose(
+        o, ref.flash_attention_ref(q, kk, v, causal, window),
+        atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("s,bq,bk,causal,window", TILINGS)
+def test_flash_kv_index_fetches_each_live_block_once(s, bq, bk, causal,
+                                                     window):
+    """The pipeline fetches a kv block when its index differs from the
+    step before: a live step holds its own block, and a skipped step
+    repeats a neighbour's, so a q block fetches its live blocks alone."""
+    nq, nk = s // bq, s // bk
+    blocks = dict(causal=causal, window=window, block_q=bq, block_k=bk,
+                  n_kv_blocks=nk)
+    fetches = 0
+    for qb in range(nq):
+        idx = [int(kv_block(qb, kb, **blocks)) for kb in range(nk)]
+        live = [kb for kb in range(nk) if idx[kb] == kb]
+        assert live == list(range(live[0], live[-1] + 1))
+        fetches += 1 + sum(a != b for a, b in zip(idx, idx[1:]))
+    assert fetches == round(nq * nk * (1 - _masked_tile_share(
+        s, bq, bk, causal, window)))
 
 
 @settings(max_examples=10, deadline=None)

@@ -42,10 +42,13 @@ def test_blk_picks_aligned_divisors(n, target, align, want):
     (ops.swiglu_blocks, (2048, 18944)),
     (ops.rglru_blocks, (4096, 4096)),
     (ops.mriq_blocks, (64 ** 3, 3072)),
+    (ops.flash_blocks, (2048, 2048)),      # qwen2-7b.prompt_heavy's prefill
+    (ops.flash_blocks, (16384, 16384)),    # granite long_prompt's prefill
 ])
 def test_compiled_blocks_at_published_widths_tile(pick, dims):
     blocks = pick(*dims, compiled=True)
     assert all(b >= 8 and d % b == 0 for b, d in zip(blocks, dims))
+    assert all(b % ops.SUBLANE == 0 or b == d for b, d in zip(blocks, dims))
     assert ops.ssd_chunk(4096, 256, compiled=True) == 256
 
 
